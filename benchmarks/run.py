@@ -73,6 +73,9 @@ def _suites() -> dict[str, object]:
 
 
 def main(argv: list[str] | None = None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     parser.add_argument(
         "--suite", action="append", default=None, metavar="NAME",

@@ -317,32 +317,6 @@ class ReplicatedStore:
 
     # -- batch ops --------------------------------------------------------------
 
-    def _pend_timeline(
-        self, state: StoreState, resource: Array, pend_apply: Array,
-        step0: Array, b: int,
-    ) -> Array:
-        """Per-op visible pending version via a timeline running max.
-
-        Each live pending slot's version activates at batch-local index
-        ``act = clip(pend_apply - step0, 0, b)`` (row ``b`` = "after the
-        batch", i.e. never); a cumulative max down the ``(b+1, R)``
-        timeline then gives, at row ``i``, the freshest pending version
-        per resource visible to op ``i``.
-        """
-        cl = state.cluster
-        n_res = cl.global_version.shape[0]
-        act = jnp.clip(
-            jnp.asarray(pend_apply, jnp.int32) - step0, 0, b
-        )
-        res_safe = jnp.where(cl.pend_live, cl.pend_resource, n_res)
-        timeline = (
-            jnp.zeros((b + 1, n_res), jnp.int32)
-            .at[act, res_safe]
-            .max(cl.pend_version, mode="drop")
-        )
-        seen = jax.lax.cummax(timeline, axis=0)
-        return seen[jnp.arange(b, dtype=jnp.int32), resource]
-
     def apply_batch(
         self,
         state: StoreState,
@@ -391,10 +365,10 @@ class ReplicatedStore:
         b = c.shape[0]
         op_index = None
         pend_apply = None
-        visible_version = None
         new_pend_apply = None
         # Every store-layer batch has affine op indices (step0 + i), so
-        # the closed-form fused ingest is always eligible on CPU.
+        # the closed-form fused ingest is always eligible on CPU.  Every
+        # path folds the pending ring's cadence visibility itself.
         impl = kernel_ops.resolve_op_ingest_impl(
             self.ingest, batch=b,
             n_clients=self.n_clients, n_replicas=self.n_replicas,
@@ -412,25 +386,6 @@ class ReplicatedStore:
                     apply_index = self.schedule_stream(c, p, k) + step0
                 pend_apply = state.pend_apply
                 new_pend_apply = apply_index
-            if impl == "fused":
-                # The fused path folds the pending ring into its own
-                # activation timeline — hand the ring straight through.
-                pass
-            elif impl != "dense":
-                # Fold the pending ring's cadence visibility in
-                # O(B + Q): batch op indices are affine, so slot q
-                # becomes visible at the batch-local activation index
-                # act = pend_apply - op_step0; scatter each live slot's
-                # version at (act, resource), run a cumulative max down
-                # the op axis, and gather at (i, r_i).  Bit-identical
-                # to the kernels' general (tile, Q) sweep (max-join is
-                # associative), without the O(B·Q) work.  The dense
-                # baseline keeps the PR-1 (B, Q) mask for the memory
-                # benchmark.
-                visible_version = self._pend_timeline(
-                    state, r, pend_apply, step0, b
-                )
-                pend_apply = None
         elif self.sync_every == 1:
             # Legacy batch entry points (no op index): intra-batch
             # merge-every-op visibility, pending ring untouched.
@@ -442,7 +397,7 @@ class ReplicatedStore:
                 self.enforce_sessions if enforce is None else enforce
             ),
             op_index=op_index, apply_index=apply_index,
-            pend_apply=pend_apply, visible_version=visible_version,
+            pend_apply=pend_apply,
             ingest=impl, with_clocks=with_clocks,
         )
         pend_apply = state.pend_apply

@@ -247,7 +247,6 @@ def apply_op_batch(
     op_index: Array | None = None,
     apply_index: Array | None = None,
     pend_apply: Array | None = None,
-    visible_version: Array | None = None,
     ingest: str | None = None,
     with_clocks: bool = True,
 ) -> BatchResult:
@@ -279,13 +278,6 @@ def apply_op_batch(
     in the pending ring from earlier batches.  With ``apply_index=None``
     the batch has plain scalar-loop semantics (coordinator-only
     visibility).  No ``(B, B)`` or ``(B, Q)`` mask crosses this API.
-
-    ``visible_version`` (``(B,)`` int32) joins an externally-computed
-    per-op visible version into the replica-visible max — the store
-    layer uses it to fold the pending ring's cadence contribution in
-    O(B + Q) (a scatter + running max over the op timeline) instead of
-    the kernel's general ``(tile, Q)`` sweep; the join is associative,
-    so the result is bit-identical to passing ``pend_apply``.
 
     ``ingest`` picks the prefix-reduction implementation
     (``repro.kernels.ops.op_ingest``): ``"dense"`` (default — the exact
@@ -324,13 +316,10 @@ def apply_op_batch(
             pend_live=state.pend_live,
             pend_apply=jnp.asarray(pend_apply, jnp.int32),
         )
-    raw0 = state.replica_version[p, r]
-    if visible_version is not None:
-        raw0 = jnp.maximum(raw0, jnp.asarray(visible_version, jnp.int32))
     occ, raw, floor = kernel_ops.op_ingest(
         c, p, r, is_w,
         state.global_version[r],
-        raw0,
+        state.replica_version[p, r],
         jnp.maximum(state.read_floor[c, r], state.write_floor[c, r]),
         op_index=op_index,
         apply_index=apply_index,

@@ -514,6 +514,31 @@ def unified_runner(
     return store, counted_run
 
 
+@functools.lru_cache(maxsize=None)
+def shard_runner(run, devices: tuple):
+    """(jitted fn, input sharding): ``run`` once per device over a
+    leading shard axis of size ``len(devices)``.
+
+    A ``shard_map`` rather than a GSPMD-partitioned ``vmap``: the
+    compiler never has to partition the Pallas calls inside ``run``,
+    and each device holds only its own shard's state.
+    """
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.asarray(devices), ("shard",))
+    spec = PartitionSpec("shard")
+
+    def local(batched, tail):
+        out = run(*jax.tree.map(lambda x: x[0], (batched, tail)))
+        return jax.tree.map(lambda x: x[None], out)
+
+    fn = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
+        check_vma=False,
+    ))
+    return fn, NamedSharding(mesh, spec)
+
+
 class EpochEngine:
     """One workload replay, device-resident end to end.
 
@@ -680,8 +705,11 @@ class EpochEngine:
 
         Returns the :meth:`prepare` dict extended with ``out`` — the
         final carry (stacked along a leading shard axis when
-        ``n_shards > 1``) — and ``per_round`` telemetry when the
-        compiled configuration emits it.
+        ``n_shards > 1``) — ``per_round`` telemetry when the compiled
+        configuration emits it, and ``layout``: how the shards were
+        placed (``"shard_map"`` over one device each when the host has
+        ``n_shards`` devices and ``use_devices`` is on, else ``"vmap"``
+        on one device, or ``"single"``) and on how many devices.
         """
         c = self.config
         prep = self.prepare(w)
@@ -695,29 +723,30 @@ class EpochEngine:
             batched_s = stack(prep["batched"])
             tail_s = stack(prep["tails"])
             devices = jax.devices()
-            if (
-                c.use_devices and c.faults is None and c.topology is None
-                and len(devices) >= c.n_shards
-            ):
-                # One tenant group per device: lay the shard axis out
-                # over a 1-D mesh; XLA partitions the vmapped program.
-                from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-                mesh = Mesh(np.asarray(devices[: c.n_shards]), ("shard",))
-                sharding = NamedSharding(mesh, PartitionSpec("shard"))
-                put = functools.partial(jax.device_put, device=sharding)
-                batched_s = jax.tree.map(put, batched_s)
-                tail_s = jax.tree.map(put, tail_s)
             _JIT_ENTRIES[0] += 1
-            out = jax.vmap(run.jitted)(batched_s, tail_s)
+            if c.use_devices and len(devices) >= c.n_shards:
+                # One tenant shard per device.  The shards share no
+                # state, so each device runs the unbatched replay on
+                # its own slice: no cross-device traffic in the scan.
+                devs = tuple(devices[: c.n_shards])
+                fn, sharding = shard_runner(run.jitted, devs)
+                put = functools.partial(jax.device_put, device=sharding)
+                out = fn(jax.tree.map(put, batched_s),
+                         jax.tree.map(put, tail_s))
+                layout = {"mode": "shard_map", "devices": len(devs)}
+            else:
+                out = jax.vmap(run.jitted)(batched_s, tail_s)
+                layout = {"mode": "vmap", "devices": 1}
         else:
             b = {k: jnp.asarray(v) for k, v in prep["batched"][0].items()}
             t = {k: jnp.asarray(v) for k, v in prep["tails"][0].items()}
             out = run(b, t)
+            layout = {"mode": "single", "devices": 1}
         if isinstance(out, tuple):
             out, per_round = out
         prep["out"] = out
         prep["per_round"] = per_round
+        prep["layout"] = layout
         return prep
 
     def run(self, w) -> dict[str, Any]:

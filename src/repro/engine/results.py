@@ -31,9 +31,15 @@ def _severity(config: EngineConfig, store, st) -> float:
     if not config.audit:
         return 0.0
     if config.n_shards > 1:
+        # The stacked logs may span devices (the shard_map layout), and
+        # a Pallas audit cannot be partitioned: fetch the small logs
+        # once and audit each shard's on the default device.
+        logs = jax.device_get(st.duot)
         sev = []
         for s in range(config.n_shards):
-            shard_st = jax.tree.map(lambda x, i=s: x[i], st)
+            shard_st = st._replace(
+                duot=jax.tree.map(lambda x, i=s: jnp.asarray(x[i]), logs)
+            )
             sev.append(float(
                 store.audit(shard_st, delta=store.delta or 0).severity
             ))

@@ -38,8 +38,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 # Packed input layout: one row per (pair, range), columns below, padded
 # to DIG_COLS so tiles stay lane-aligned.  Inert rows have VALID=0 and
@@ -57,12 +56,19 @@ OUT_COLS = 4
 def compare_tile(tile: jax.Array) -> jax.Array:
     """Verdicts for one ``(block, DIG_COLS)`` tile — the one shared
     implementation of the compare math (integer-only, so the Pallas
-    kernel, the jnp twin, and the dense oracle agree bit-for-bit)."""
-    d_sum = tile[:, A_SUM] - tile[:, B_SUM]
-    d_max = tile[:, A_MAX] - tile[:, B_MAX]
-    d_chk = tile[:, A_CHK] - tile[:, B_CHK]
-    d_cnt = tile[:, A_CNT] - tile[:, B_CNT]
-    valid = tile[:, VALID] > 0
+    kernel, the jnp twin, and the dense oracle agree bit-for-bit).
+
+    Columns are taken as ``(block, 1)`` slices and the verdicts written
+    by a lane select: Mosaic lowers neither a stack of boolean vectors
+    nor a column scatter."""
+    def col(k):
+        return tile[:, k:k + 1]
+
+    d_sum = col(A_SUM) - col(B_SUM)
+    d_max = col(A_MAX) - col(B_MAX)
+    d_chk = col(A_CHK) - col(B_CHK)
+    d_cnt = col(A_CNT) - col(B_CNT)
+    valid = col(VALID) > 0
     differ = valid & (
         (d_sum != 0) | (d_max != 0) | (d_chk != 0) | (d_cnt != 0)
     )
@@ -71,9 +77,12 @@ def compare_tile(tile: jax.Array) -> jax.Array:
     tie = (d_max == 0) & (d_sum == 0)
     a_behind = differ & ((d_max < 0) | ((d_max == 0) & (d_sum < 0)) | tie)
     b_behind = differ & ((d_max > 0) | ((d_max == 0) & (d_sum > 0)) | tie)
-    zeros = jnp.zeros_like(differ)
-    return jnp.stack(
-        [differ, a_behind, b_behind, zeros], axis=1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tile.shape[0], OUT_COLS), 1)
+    return jnp.where(
+        ((lane == DIFFER) & differ)
+        | ((lane == A_BEHIND) & a_behind)
+        | ((lane == B_BEHIND) & b_behind),
+        1, 0,
     ).astype(jnp.int32)
 
 
@@ -115,7 +124,7 @@ def digest_compare_pallas(
         in_specs=[pl.BlockSpec((block, DIG_COLS), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block, OUT_COLS), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, OUT_COLS), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # Row tiles are independent; let the compiler parallelize.
             dimension_semantics=("parallel",),
         ),

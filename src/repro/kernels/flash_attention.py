@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -2.0 ** 30
 
@@ -149,7 +148,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
             pltpu.VMEM((block_q, hd), jnp.float32),  # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

@@ -42,8 +42,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 # Per-row bin params layout: (M, 2) f32.
 LO, INV_W = 0, 1
@@ -137,7 +137,7 @@ def histogram_pallas(
         ],
         out_specs=pl.BlockSpec((m, n_bins), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n_bins), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # Column tiles revisit the same output block; the grid must
             # run in order.
             dimension_semantics=("arbitrary",),

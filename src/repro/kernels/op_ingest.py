@@ -16,13 +16,13 @@ blocks of the batch:
     pairs ``(t, u <= t)``; each row tile accumulates its partial
     sums/maxima into its output block across the column tiles
     ``u < t``, then at the diagonal step ``u == t`` folds the
-    intra-tile lower triangle, the pending ring, and the gathered
-    state vectors, and publishes the tile's write versions and floor
-    contributions into a persistent ``(B, 2)`` buffer that later row
+    intra-tile lower triangle, the pending ring (in lane chunks), and
+    the gathered state vectors, and publishes the tile's write versions
+    and floor contributions into a VMEM scratch buffer that later row
     tiles read — per-step memory is O(tile² + B + Q), never O(B²).
-  * :func:`op_ingest_tiled` — the same block walk as a ``lax.scan``
-    over row tiles in plain jnp (one ``(tile, B)`` strip per step),
-    the fast path on CPU where Pallas runs interpreted.
+  * :func:`op_ingest_tiled` — the same tile-pair walk as a
+    ``lax.scan`` over the identical tile math in plain jnp, the
+    jnp twin the kernel is checked against on the chip.
 
 Visibility inside a tile is the closed-form cadence predicate (no
 precomputed masks cross the API):
@@ -47,8 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.ref import NEVER, op_ingest_ref
 
 Array = jax.Array
@@ -57,20 +57,22 @@ Array = jax.Array
 CLIENT, REPLICA, RESOURCE, IS_WRITE, GLOBAL0, RAW0, FLOOR0 = 0, 1, 2, 3, 4, 5, 6
 OPIDX, APPLYIDX = 7, 8
 OP_COLS = 16
-# pending meta columns (Q, PEND_COLS) int32
+# pending meta rows (PEND_ROWS, lanes) int32, chunked along the ring
 PVER, PRES, PLIVE, PAPPLY = 0, 1, 2, 3
-PEND_COLS = 8
+PEND_ROWS = 8
+PEND_CHUNK = 512
 # output columns (B, OUT_COLS) int32
 OCC, RAW, FLOOR = 0, 1, 2
 OUT_COLS = 8
-# persistent tile-exchange buffer columns (B, BUF_COLS) int32
+# tile-exchange buffer rows (n_tiles, BUF_ROWS, block) int32
 VERW, CONTRIB = 0, 1
-BUF_COLS = 8
+BUF_ROWS = 8
 
 
 class _Packed(NamedTuple):
-    meta: Array       # (Bp, OP_COLS) int32
-    pend: Array       # (Qp, PEND_COLS) int32
+    meta: Array       # (Bp, OP_COLS) int32 — one op per row
+    meta_t: Array     # (OP_COLS, Bp) int32 — one op per lane
+    pend: Array       # (n_chunks, PEND_ROWS, chunk) int32 — one slot per lane
     b: int            # true batch length (rows beyond it are inert pads)
 
 
@@ -91,14 +93,16 @@ def pack_ops(
     pend_apply: Array | None = None,
     block: int = 128,
 ) -> _Packed:
-    """Pack the per-op vectors into the kernel's meta layout.
+    """Pack the per-op vectors into the kernel's meta layouts.
 
     Pads the batch to a ``block`` multiple with inert rows (reads on
     resource ``-1`` — they match nothing and sort after every real op,
-    so they contribute to no reduction) and the pending ring to a lane
-    multiple with dead slots.  ``apply_index=None`` (scalar semantics)
-    packs the ``NEVER`` sentinel so the cadence predicate is vacuously
-    false.
+    so they contribute to no reduction) and the pending ring to a whole
+    number of lane chunks with dead slots.  ``apply_index=None`` (scalar
+    semantics) packs the ``NEVER`` sentinel so the cadence predicate is
+    vacuously false.  The ops are packed twice — one op per row (the
+    row side of a tile pair) and one op per lane (the column side) — so
+    the kernel never transposes a vector.
     """
     b = client.shape[0]
     pad = (-b) % block
@@ -107,50 +111,62 @@ def pack_ops(
         x = jnp.asarray(x, jnp.int32)
         return jnp.pad(x, (0, pad), constant_values=fill) if pad else x
 
-    bp = b + pad
-    meta = jnp.zeros((bp, OP_COLS), jnp.int32)
-    meta = meta.at[:, CLIENT].set(pcol(client))
-    meta = meta.at[:, REPLICA].set(pcol(replica, -1))
-    meta = meta.at[:, RESOURCE].set(pcol(resource, -1))
-    meta = meta.at[:, IS_WRITE].set(
-        pcol(jnp.asarray(is_write).astype(jnp.int32))
-    )
-    meta = meta.at[:, GLOBAL0].set(pcol(g0))
-    meta = meta.at[:, RAW0].set(pcol(raw0))
-    meta = meta.at[:, FLOOR0].set(pcol(floor0))
-    meta = meta.at[:, OPIDX].set(
-        pcol(jnp.zeros((b,), jnp.int32) if op_index is None else op_index)
-    )
-    meta = meta.at[:, APPLYIDX].set(
-        pcol(
+    zeros = jnp.zeros((b,), jnp.int32)
+    cols = {
+        CLIENT: pcol(client),
+        REPLICA: pcol(replica, -1),
+        RESOURCE: pcol(resource, -1),
+        IS_WRITE: pcol(jnp.asarray(is_write).astype(jnp.int32)),
+        GLOBAL0: pcol(g0),
+        RAW0: pcol(raw0),
+        FLOOR0: pcol(floor0),
+        OPIDX: pcol(zeros if op_index is None else op_index),
+        APPLYIDX: pcol(
             jnp.full((b,), NEVER, jnp.int32)
             if apply_index is None else apply_index,
             NEVER,
-        )
-    )
+        ),
+    }
+    blank = jnp.zeros((b + pad,), jnp.int32)
+    meta_t = jnp.stack([cols.get(k, blank) for k in range(OP_COLS)])
 
     q = 0 if pend_version is None else pend_version.shape[0]
-    qp = max(8, q + (-q) % 8)
-    pend = jnp.zeros((qp, PEND_COLS), jnp.int32)
-    pend = pend.at[:, PRES].set(-1)
-    if q:
-        pend = pend.at[:q, PVER].set(jnp.asarray(pend_version, jnp.int32))
-        pend = pend.at[:q, PRES].set(jnp.asarray(pend_resource, jnp.int32))
-        pend = pend.at[:q, PLIVE].set(
-            jnp.asarray(pend_live).astype(jnp.int32)
-        )
-        pend = pend.at[:q, PAPPLY].set(
-            jnp.full((q,), NEVER, jnp.int32)
-            if pend_apply is None
-            else jnp.asarray(pend_apply, jnp.int32)
-        )
-    return _Packed(meta=meta, pend=pend, b=b)
+    chunk = min(PEND_CHUNK, max(128, q + (-q) % 128))
+    qp = max(chunk, q + (-q) % chunk)
+
+    def prow(x, fill):
+        x = jnp.asarray(x, jnp.int32)
+        return jnp.pad(x, (0, qp - q), constant_values=fill)
+
+    dead = jnp.zeros((0,), jnp.int32)
+    rows = {
+        PVER: prow(dead if not q else pend_version, 0),
+        PRES: prow(dead if not q else pend_resource, -1),
+        PLIVE: prow(dead if not q else jnp.asarray(pend_live), 0),
+        PAPPLY: prow(
+            dead if not q else (
+                jnp.full((q,), NEVER, jnp.int32) if pend_apply is None
+                else pend_apply
+            ),
+            NEVER,
+        ),
+    }
+    pblank = jnp.zeros((qp,), jnp.int32)
+    pend = jnp.stack([rows.get(k, pblank) for k in range(PEND_ROWS)])
+    pend = pend.reshape(PEND_ROWS, qp // chunk, chunk).swapaxes(0, 1)
+    return _Packed(meta=meta_t.T, meta_t=meta_t, pend=pend, b=b)
 
 
 # -- shared tile math (identical jnp ops in the Pallas body and the scan) ----
+#
+# A tile pair is ``rows`` (block, OP_COLS) — op i on sublane i — against
+# ``cols`` (OP_COLS, block) — op j on lane j.  ``rows[:, k:k+1]`` is then
+# a column vector and ``cols[k:k+1, :]`` a row vector, so every pair
+# relation is a plain (block, block) broadcast and every per-op result a
+# (block, 1) lane reduction: the Mosaic-friendly shapes.
 
 
-def _pair_parts(rows: Array, cols: Array, prior: Array):
+def _pair_parts(rows: Array, cols: Array, prior):
     """Relation masks for one (rows × cols) block.
 
     ``prior`` is the order mask (row's global index > col's).  Returns
@@ -158,91 +174,142 @@ def _pair_parts(rows: Array, cols: Array, prior: Array):
     cadence-visible subset, and the session-floor (same client &
     resource) pairs.
     """
-    same_r = rows[:, RESOURCE][:, None] == cols[:, RESOURCE][None, :]
-    prior_w = prior & same_r & (cols[:, IS_WRITE][None, :] > 0)
+    def rc(k):
+        return rows[:, k:k + 1]
+
+    def cr(k):
+        return cols[k:k + 1, :]
+
+    same_r = rc(RESOURCE) == cr(RESOURCE)
+    prior_w = prior & same_r & (cr(IS_WRITE) > 0)
     vis = prior_w & (
-        (rows[:, REPLICA][:, None] == cols[:, REPLICA][None, :])
-        | (rows[:, OPIDX][:, None] >= cols[:, APPLYIDX][None, :])
+        (rc(REPLICA) == cr(REPLICA)) | (rc(OPIDX) >= cr(APPLYIDX))
     )
-    floor_mask = prior & same_r & (
-        rows[:, CLIENT][:, None] == cols[:, CLIENT][None, :]
-    )
+    floor_mask = prior & same_r & (rc(CLIENT) == cr(CLIENT))
     return prior_w, vis, floor_mask
 
 
-def _cross_parts(rows: Array, cols: Array, prior: Array, buf: Array):
-    """Partial reductions of one already-finalized column block."""
-    prior_w, vis, floor_mask = _pair_parts(rows, cols, prior)
-    occ_part = jnp.sum(prior_w, axis=1, dtype=jnp.int32)
-    vis_part = jnp.max(jnp.where(vis, buf[:, VERW][None, :], 0), axis=1)
-    floor_part = jnp.max(
-        jnp.where(floor_mask, buf[:, CONTRIB][None, :], 0), axis=1
+def _masked_max(mask: Array, row: Array) -> Array:
+    """(block, 1) max over lanes of ``row`` where ``mask`` (identity 0)."""
+    return jnp.max(jnp.where(mask, row, 0), axis=1, keepdims=True)
+
+
+def _count(mask: Array) -> Array:
+    return jnp.sum(jnp.where(mask, 1, 0), axis=1, keepdims=True)
+
+
+def _col_to_row(col: Array) -> Array:
+    """(n, 1) -> (1, n) by a diagonal select and a sublane reduction —
+    exact for integers, and no transpose for Mosaic to lower."""
+    n = col.shape[0]
+    iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32, (n, n))
+    return jnp.sum(jnp.where(iota(0) == iota(1), col, 0), axis=0,
+                   keepdims=True)
+
+
+def _cross_parts(rows: Array, cols: Array, buf: Array):
+    """Partial reductions of one already-finalized column block.
+
+    Every pair of a strictly-cross tile is ordered (row indices all
+    exceed column indices), so the order mask is just True."""
+    prior_w, vis, floor_mask = _pair_parts(rows, cols, True)
+    return (
+        _count(prior_w),
+        _masked_max(vis, buf[VERW:VERW + 1, :]),
+        _masked_max(floor_mask, buf[CONTRIB:CONTRIB + 1, :]),
     )
-    return occ_part, vis_part, floor_part
 
 
-def _finalize_tile(
-    rows: Array, occ_acc: Array, vis_acc: Array, floor_acc: Array,
-    pend: Array,
-):
+def _accumulate(acc: Array, rows: Array, cols: Array, buf: Array) -> Array:
+    """Fold one cross tile's partials into the row tile's accumulator."""
+    occ_p, vis_p, floor_p = _cross_parts(rows, cols, buf)
+    return _with_cols(
+        acc,
+        acc[:, OCC:OCC + 1] + occ_p,
+        jnp.maximum(acc[:, RAW:RAW + 1], vis_p),
+        jnp.maximum(acc[:, FLOOR:FLOOR + 1], floor_p),
+    )
+
+
+def _with_cols(out: Array, occ: Array, raw: Array, floor: Array) -> Array:
+    """``out`` with its OCC/RAW/FLOOR columns replaced — a lane select,
+    since Mosaic has no column scatter."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+    return jnp.where(
+        lane == OCC, occ,
+        jnp.where(lane == RAW, raw, jnp.where(lane == FLOOR, floor, out)),
+    )
+
+
+def _pend_max(rows: Array, n_chunks: int, chunk_of) -> Array:
+    """(block, 1) freshest cadence-visible pending-ring version per op.
+
+    Walks the ring ``chunk_of(k)`` — (PEND_ROWS, chunk) slices — so the
+    live mask is (block, chunk), never (block, Q)."""
+    def body(k, acc):
+        pend = chunk_of(k)
+        pvis = (
+            (pend[PLIVE:PLIVE + 1, :] > 0)
+            & (rows[:, RESOURCE:RESOURCE + 1] == pend[PRES:PRES + 1, :])
+            & (rows[:, OPIDX:OPIDX + 1] >= pend[PAPPLY:PAPPLY + 1, :])
+        )
+        return jnp.maximum(acc, _masked_max(pvis, pend[PVER:PVER + 1, :]))
+
+    init = jnp.zeros((rows.shape[0], 1), jnp.int32)
+    return jax.lax.fori_loop(0, n_chunks, body, init)
+
+
+def _finalize_tile(rows: Array, cols: Array, acc: Array, n_chunks: int,
+                   chunk_of):
     """Diagonal step: intra-tile triangle + pending ring + state joins.
 
-    ``occ/vis/floor_acc`` are the accumulated cross-tile partials.
-    Returns the tile's final ``(occ, raw, floor)`` plus its
-    ``(verw, contrib)`` buffer row for later tiles.
+    ``acc`` holds the accumulated cross-tile partials in its OCC/RAW/
+    FLOOR columns; ``cols`` is the same tile one op per lane.  Returns
+    the tile's final output block plus its (BUF_ROWS, block) buffer
+    rows (write versions and floor contributions) for later tiles.
     """
     t = rows.shape[0]
     iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32, (t, t))
-    prior = iota(0) > iota(1)
-    prior_w, vis, floor_mask = _pair_parts(rows, rows, prior)
+    prior_w, vis, floor_mask = _pair_parts(rows, cols, iota(0) > iota(1))
 
-    occ = occ_acc + jnp.sum(prior_w, axis=1, dtype=jnp.int32)
-    is_w = rows[:, IS_WRITE] > 0
-    ver_w = rows[:, GLOBAL0] + occ + 1
-    verw = jnp.where(is_w, ver_w, 0)
+    occ = acc[:, OCC:OCC + 1] + _count(prior_w)
+    is_w = rows[:, IS_WRITE:IS_WRITE + 1] > 0
+    ver_w = rows[:, GLOBAL0:GLOBAL0 + 1] + occ + 1
+    verw = _col_to_row(jnp.where(is_w, ver_w, 0))
 
-    vis_max = jnp.maximum(
-        vis_acc, jnp.max(jnp.where(vis, verw[None, :], 0), axis=1)
+    raw = jnp.maximum(
+        jnp.maximum(rows[:, RAW0:RAW0 + 1], acc[:, RAW:RAW + 1]),
+        jnp.maximum(_masked_max(vis, verw),
+                    _pend_max(rows, n_chunks, chunk_of)),
     )
-    pvis = (
-        (pend[:, PLIVE][None, :] > 0)
-        & (rows[:, RESOURCE][:, None] == pend[:, PRES][None, :])
-        & (rows[:, OPIDX][:, None] >= pend[:, PAPPLY][None, :])
-    )
-    pend_max = jnp.max(jnp.where(pvis, pend[:, PVER][None, :], 0), axis=1)
-    raw = jnp.maximum(jnp.maximum(rows[:, RAW0], vis_max), pend_max)
-
-    contrib = jnp.where(is_w, ver_w, raw)
+    contrib = _col_to_row(jnp.where(is_w, ver_w, raw))
     floor = jnp.maximum(
-        jnp.maximum(rows[:, FLOOR0], floor_acc),
-        jnp.max(jnp.where(floor_mask, contrib[None, :], 0), axis=1),
+        jnp.maximum(rows[:, FLOOR0:FLOOR0 + 1], acc[:, FLOOR:FLOOR + 1]),
+        _masked_max(floor_mask, contrib),
     )
-    return occ, raw, floor, verw, contrib
+    out = _with_cols(jnp.zeros(acc.shape, jnp.int32), occ, raw, floor)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (BUF_ROWS, t), 0)
+    buf = jnp.where(sub == VERW, verw, jnp.where(sub == CONTRIB, contrib, 0))
+    return out, buf
+
+
+def _tri_schedule(nb: int) -> tuple[np.ndarray, np.ndarray]:
+    """(t, u) of every step of the lower-triangular (t, u <= t) walk:
+    for each row tile, its cross partials in column order, then its
+    diagonal finalize (which publishes the tile's buffer rows for later
+    row tiles)."""
+    ts = np.repeat(np.arange(nb, dtype=np.int32), np.arange(1, nb + 1))
+    us = np.concatenate([np.arange(t + 1, dtype=np.int32) for t in range(nb)])
+    return ts, us
 
 
 # -- Pallas kernel -----------------------------------------------------------
 
 
-def _tri_coords(i):
-    """(t, u) of the i-th step of the lower-triangular (t, u <= t) walk.
-
-    ``t = floor((sqrt(8i+1)-1)/2)`` in f32, then corrected by ±1
-    against the exact integer triangular numbers — f32 rounding error
-    is far below 1 for any realistic tile count, and the correction
-    makes the mapping exact regardless.
-    """
-    i = i.astype(jnp.int32)
-    f = (jnp.sqrt(8.0 * i.astype(jnp.float32) + 1.0) - 1.0) * 0.5
-    t = f.astype(jnp.int32)
-    t = jnp.where(t * (t + 1) // 2 > i, t - 1, t)
-    t = jnp.where((t + 1) * (t + 2) // 2 <= i, t + 1, t)
-    u = i - t * (t + 1) // 2
-    return t, u
-
-
-def _op_ingest_kernel(rows_ref, cols_ref, pend_ref, out_ref, buf_ref,
-                      *, block: int):
-    t, u = _tri_coords(pl.program_id(0))
+def _op_ingest_kernel(ts_ref, us_ref, rows_ref, cols_ref, pend_ref, out_ref,
+                      buf_ref):
+    i = pl.program_id(0)
+    t, u = ts_ref[i], us_ref[i]
 
     @pl.when(u == 0)
     def _init():
@@ -250,73 +317,56 @@ def _op_ingest_kernel(rows_ref, cols_ref, pend_ref, out_ref, buf_ref,
 
     @pl.when(u < t)
     def _cross():
-        rows = rows_ref[...]
-        cols = cols_ref[...]
-        buf = buf_ref[pl.ds(u * block, block), :]
-        # Every pair of a strictly-cross tile is ordered (row indices
-        # all exceed column indices), so the order mask is just True.
-        occ_p, vis_p, floor_p = _cross_parts(rows, cols, True, buf)
-        out = out_ref[...]
-        out = out.at[:, OCC].set(out[:, OCC] + occ_p)
-        out = out.at[:, RAW].set(jnp.maximum(out[:, RAW], vis_p))
-        out = out.at[:, FLOOR].set(jnp.maximum(out[:, FLOOR], floor_p))
-        out_ref[...] = out
+        out_ref[...] = _accumulate(
+            out_ref[...], rows_ref[...], cols_ref[...], buf_ref[u]
+        )
 
     @pl.when(u == t)
     def _diag():
-        rows = rows_ref[...]
-        acc = out_ref[...]
-        occ, raw, floor, verw, contrib = _finalize_tile(
-            rows, acc[:, OCC], acc[:, RAW], acc[:, FLOOR], pend_ref[...]
+        out, buf = _finalize_tile(
+            rows_ref[...], cols_ref[...], out_ref[...],
+            pend_ref.shape[0], lambda k: pend_ref[k],
         )
-        out = jnp.zeros(out_ref.shape, jnp.int32)
-        out = out.at[:, OCC].set(occ)
-        out = out.at[:, RAW].set(raw)
-        out = out.at[:, FLOOR].set(floor)
         out_ref[...] = out
-        buf = jnp.zeros((block, BUF_COLS), jnp.int32)
-        buf = buf.at[:, VERW].set(verw)
-        buf = buf.at[:, CONTRIB].set(contrib)
-        buf_ref[pl.ds(t * block, block), :] = buf
+        buf_ref[t] = buf
 
 
 def op_ingest_pallas(
     packed: _Packed, *, block: int = 128, interpret: bool = False
 ) -> tuple[Array, Array, Array]:
     """Tiled ingest via ``pallas_call``.  Returns ``(occ, raw, floor)``."""
-    meta, pend, b = packed
+    meta, meta_t, pend, b = packed
     bp = meta.shape[0]
-    qp = pend.shape[0]
     assert bp % block == 0, f"padded B={bp} must tile into block={block}"
     nb = bp // block
+    ts, us = _tri_schedule(nb)
 
-    row_of = lambda i: (_tri_coords(i)[0], 0)                # noqa: E731
-    col_of = lambda i: (_tri_coords(i)[1], 0)                # noqa: E731
-    out, _ = pl.pallas_call(
-        functools.partial(_op_ingest_kernel, block=block),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         # One step per ordered tile pair (t, u <= t) — the grid walks
         # only the lower triangle, nothing is fetched for u > t.
-        grid=(nb * (nb + 1) // 2,),
+        grid=(len(ts),),
         in_specs=[
-            pl.BlockSpec((block, OP_COLS), row_of),
-            pl.BlockSpec((block, OP_COLS), col_of),
-            pl.BlockSpec((qp, PEND_COLS), lambda i: (0, 0)),
+            pl.BlockSpec((block, OP_COLS), lambda i, ts, us: (ts[i], 0)),
+            pl.BlockSpec((OP_COLS, block), lambda i, ts, us: (0, us[i])),
+            pl.BlockSpec(pend.shape, lambda i, ts, us: (0, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block, OUT_COLS), row_of),
-            pl.BlockSpec((bp, BUF_COLS), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bp, OUT_COLS), jnp.int32),
-            jax.ShapeDtypeStruct((bp, BUF_COLS), jnp.int32),
-        ],
-        compiler_params=CompilerParams(
+        out_specs=pl.BlockSpec(
+            (block, OUT_COLS), lambda i, ts, us: (ts[i], 0)
+        ),
+        scratch_shapes=[pltpu.VMEM((nb, BUF_ROWS, block), jnp.int32)],
+    )
+    out = pl.pallas_call(
+        _op_ingest_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bp, OUT_COLS), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
             # Row tiles accumulate across column steps and read buffer
             # rows published by earlier diagonal steps: strict order.
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(meta, meta, pend)
+    )(jnp.asarray(ts), jnp.asarray(us), meta, meta_t, pend)
     return out[:b, OCC], out[:b, RAW], out[:b, FLOOR]
 
 
@@ -327,58 +377,37 @@ def op_ingest_tiled(packed: _Packed, *, block: int = 256):
     """The kernel's block walk as a ``lax.scan`` over tile *pairs*.
 
     Walks the same lower-triangular ``(t, u <= t)`` tile-pair sequence
-    as the Pallas grid — a ``lax.switch`` picks the cross-tile partial
-    step or the diagonal finalize step — so only the ~B²/2 ordered
-    pairs are ever touched and every step works on ``(block, block)``
-    tiles: peak memory O(B·block) for the carried accumulators, never
-    O(B²).
+    as the Pallas grid — a ``lax.cond`` picks the cross-tile partial
+    step or the diagonal finalize step, both the kernel's own tile math
+    — so only the ~B²/2 ordered pairs are ever touched and every step
+    works on ``(block, block)`` tiles: peak memory O(B·block) for the
+    carried accumulators, never O(B²).
     """
-    meta, pend, b = packed
+    meta, meta_t, pend, b = packed
     bp = meta.shape[0]
     nb = bp // block
+    ts, us = _tri_schedule(nb)
 
-    # Static triangular schedule: for each row tile, its cross partials
-    # in column order, then its diagonal finalize (which publishes the
-    # tile's verw/contrib for later row tiles — same order the Pallas
-    # grid executes).
-    ts, us = [], []
-    for t in range(nb):
-        for u in range(t + 1):
-            ts.append(t)
-            us.append(u)
-    schedule = (
-        jnp.asarray(np.asarray(ts, np.int32)),
-        jnp.asarray(np.asarray(us, np.int32)),
-    )
+    def tiles(t, u, out):
+        rows = jax.lax.dynamic_slice(meta, (t * block, 0), (block, OP_COLS))
+        cols = jax.lax.dynamic_slice(meta_t, (0, u * block), (OP_COLS, block))
+        acc = jax.lax.dynamic_slice(out, (t * block, 0), (block, OUT_COLS))
+        return rows, cols, acc
 
     def cross(carry, t, u):
-        buf, acc, out = carry
-        rows = jax.lax.dynamic_slice(meta, (t * block, 0), (block, OP_COLS))
-        cols = jax.lax.dynamic_slice(meta, (u * block, 0), (block, OP_COLS))
-        bufu = jax.lax.dynamic_slice(buf, (u * block, 0), (block, BUF_COLS))
-        occ_p, vis_p, floor_p = _cross_parts(rows, cols, True, bufu)
-        acct = jax.lax.dynamic_slice(acc, (t * block, 0), (block, 4))
-        acct = acct.at[:, OCC].add(occ_p)
-        acct = acct.at[:, RAW].max(vis_p)
-        acct = acct.at[:, FLOOR].max(floor_p)
-        acc = jax.lax.dynamic_update_slice(acc, acct, (t * block, 0))
-        return buf, acc, out
+        buf, out = carry
+        rows, cols, acc = tiles(t, u, out)
+        acc = _accumulate(acc, rows, cols, buf[u])
+        return buf, jax.lax.dynamic_update_slice(out, acc, (t * block, 0))
 
     def diag(carry, t, u):
-        del u
-        buf, acc, out = carry
-        rows = jax.lax.dynamic_slice(meta, (t * block, 0), (block, OP_COLS))
-        acct = jax.lax.dynamic_slice(acc, (t * block, 0), (block, 4))
-        occ, raw, floor, verw, contrib = _finalize_tile(
-            rows, acct[:, OCC], acct[:, RAW], acct[:, FLOOR], pend
+        buf, out = carry
+        rows, cols, acc = tiles(t, u, out)
+        acc, buft = _finalize_tile(
+            rows, cols, acc, pend.shape[0], lambda k: pend[k]
         )
-        outt = jnp.stack([occ, raw, floor, jnp.zeros_like(occ)], axis=1)
-        out = jax.lax.dynamic_update_slice(out, outt, (t * block, 0))
-        buft = jnp.zeros((block, BUF_COLS), jnp.int32)
-        buft = buft.at[:, VERW].set(verw)
-        buft = buft.at[:, CONTRIB].set(contrib)
-        buf = jax.lax.dynamic_update_slice(buf, buft, (t * block, 0))
-        return buf, acc, out
+        out = jax.lax.dynamic_update_slice(out, acc, (t * block, 0))
+        return buf.at[t].set(buft), out
 
     def step(carry, tu):
         t, u = tu
@@ -390,10 +419,11 @@ def op_ingest_tiled(packed: _Packed, *, block: int = 256):
         )
         return carry, None
 
-    zeros = lambda w: jnp.zeros((bp, w), jnp.int32)          # noqa: E731
-    (_, _, out), _ = jax.lax.scan(
-        step, (zeros(BUF_COLS), zeros(4), zeros(4)), schedule
+    init = (
+        jnp.zeros((nb, BUF_ROWS, block), jnp.int32),
+        jnp.zeros((bp, OUT_COLS), jnp.int32),
     )
+    (_, out), _ = jax.lax.scan(step, init, (jnp.asarray(ts), jnp.asarray(us)))
     return out[:b, OCC], out[:b, RAW], out[:b, FLOOR]
 
 

@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On a TPU runtime the kernels compile natively; on CPU (this container,
-CI) they run in interpret mode — same code path, Python-executed kernel
-body — which is how the correctness sweeps in ``tests/test_kernels.py``
-validate them against the ``ref.py`` oracles.
+On a TPU runtime the kernels compile natively; on CPU (tests, CI) they
+run in interpret mode — same code path, Python-executed kernel body —
+which is how the correctness sweeps in ``tests/test_kernels.py``
+validate them against the ``ref.py`` oracles.  A kernel never runs
+interpreted on a TPU: asking for it raises.
 """
 
 from __future__ import annotations
@@ -25,6 +26,18 @@ from repro.kernels import vclock_audit as _va
 
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
+
+
+def _interpret(interpret: bool | None) -> bool:
+    """Interpret mode off the TPU, compiled on it — never interpreted
+    there, where a slow Python kernel body would pass unnoticed."""
+    if interpret is None:
+        return _on_cpu()
+    if interpret and jax.default_backend() == "tpu":
+        raise ValueError(
+            "Pallas kernels run compiled on a TPU, not interpreted"
+        )
+    return interpret
 
 
 def resolve_op_ingest_impl(
@@ -164,7 +177,7 @@ def op_ingest(
     if impl == "tiled":
         return _oi.op_ingest_tiled(packed, block=block)
     if impl == "pallas":
-        interpret = _on_cpu() if interpret is None else interpret
+        interpret = _interpret(interpret)
         return _oi.op_ingest_pallas(packed, block=block, interpret=interpret)
     raise ValueError(f"unknown op_ingest impl: {impl!r}")
 
@@ -206,7 +219,7 @@ def digest_compare(
     if impl == "tiled":
         out = _dc.digest_compare_tiled(packed, block=block)
     elif impl == "pallas":
-        interpret = _on_cpu() if interpret is None else interpret
+        interpret = _interpret(interpret)
         out = _dc.digest_compare_pallas(
             packed, block=block, interpret=interpret
         )
@@ -273,7 +286,7 @@ def histogram(
                 vals, msk, params, n_bins=n_bins, block=block
             )
         elif impl == "pallas":
-            interpret = _on_cpu() if interpret is None else interpret
+            interpret = _interpret(interpret)
             out = _hg.histogram_pallas(
                 vals, msk, params, n_bins=n_bins, block=block,
                 interpret=interpret,
@@ -301,7 +314,7 @@ def flash_attention(
     substrate's layout; internally transposed to the kernel's (B, H, S,
     hd).  layout 'bhsd': already kernel-native.
     """
-    interpret = _on_cpu() if interpret is None else interpret
+    interpret = _interpret(interpret)
     if layout == "bshd":
         q = jnp.swapaxes(q, 1, 2)
         k = jnp.swapaxes(k, 1, 2)
@@ -321,7 +334,7 @@ def audit_duot(duot, *, delta: int = 0, block: int = 128,
 
     Returns the (M, M) packed code matrix (phase | viol<<8 | timed<<9).
     The log is padded to a block multiple with invalid entries."""
-    interpret = _on_cpu() if interpret is None else interpret
+    interpret = _interpret(interpret)
     m = duot.capacity
     pad = (-m) % block
     def p(x, fill=0):
@@ -360,8 +373,17 @@ def session_admit(
 
     Same contract as ``repro.kernels.ref.session_admit_ref``: returns
     ``(served, admissible, floor, new_read_floor)``.  The batch is
-    padded to a block multiple with invalid rows."""
-    interpret = _on_cpu() if interpret is None else interpret
+    padded to a block multiple with invalid rows.
+
+    The kernel does not lower for TPU yet (column scatters and a
+    ``(B, C, R)`` product in its body), so only interpret mode runs it.
+    """
+    interpret = _interpret(interpret)
+    if not interpret:
+        raise NotImplementedError(
+            "session_floor has no TPU lowering yet; admit with "
+            "use_kernel=False"
+        )
     b = client.shape[0]
     block = max(1, min(block, b))
     pad = (-b) % block
@@ -404,7 +426,7 @@ def policy_score(
     block multiple with invalid rows, which score utility 0/feasible 0
     and are stripped before returning.
     """
-    interpret = _on_cpu() if interpret is None else interpret
+    interpret = _interpret(interpret)
     s = stale.shape[0]
     block_s = max(1, min(block_s, s))
     pad = (-s) % block_s
@@ -469,7 +491,7 @@ def placement_score(
             max_latency_ms=max_latency_ms, block_r=block_r,
         )
     elif impl == "pallas":
-        interpret = _on_cpu() if interpret is None else interpret
+        interpret = _interpret(interpret)
         util, feas = _pls.placement_score(
             reads, writes, read_price, write_price, read_rtt, cand_meta,
             max_latency_ms=max_latency_ms, block_r=block_r,

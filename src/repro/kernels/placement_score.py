@@ -29,8 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.ref import INFEASIBLE_PENALTY, STRUCTURAL_WEIGHT
 
 
@@ -40,32 +40,31 @@ def _placement_score_kernel(
 ):
     reads = reads_ref[...]          # (br, G)
     writes = writes_ref[...]        # (br, G)
-    rprice = rprice_ref[...]        # (K, G)
-    wprice = wprice_ref[...]        # (K, G)
-    rtt = rtt_ref[...]              # (K, G)
+    # Candidate tables arrive one candidate per lane, so region ``gi``
+    # of every candidate is the (1, K) row ``x[gi:gi + 1, :]``.
+    rprice = rprice_ref[...]        # (G, K)
+    wprice = wprice_ref[...]        # (G, K)
+    rtt = rtt_ref[...]              # (G, K)
     meta = meta_ref[...]            # (2, K)
 
     br, g = reads.shape
-    k = rprice.shape[0]
-    store = meta[0][None, :]
-    valid = meta[1][None, :] > 0.0
+    k = rprice.shape[1]
+    store = meta[0:1, :]
+    valid = meta[1:2, :] > 0.0
     max_lat = jnp.float32(max_latency_ms)
     structural = jnp.float32(STRUCTURAL_WEIGHT)
 
     cost = jnp.broadcast_to(store, (br, k))
     excess = jnp.zeros((br, k), jnp.float32)
     for gi in range(g):             # static, fixed order — bit-exact twin
-        cost = cost + reads[:, gi:gi + 1] * rprice[None, :, gi]
-        cost = cost + writes[:, gi:gi + 1] * wprice[None, :, gi]
+        cost = cost + reads[:, gi:gi + 1] * rprice[gi:gi + 1, :]
+        cost = cost + writes[:, gi:gi + 1] * wprice[gi:gi + 1, :]
         demand = (reads[:, gi:gi + 1] + writes[:, gi:gi + 1]) > 0.0
-        late = rtt[None, :, gi] > max_lat
-        excess = excess + structural * jnp.logical_and(
-            demand, late
-        ).astype(jnp.float32)
-    excess = excess + structural * jnp.logical_not(valid).astype(jnp.float32)
-    feas = excess == 0.0
+        late = rtt[gi:gi + 1, :] > max_lat
+        excess = excess + jnp.where(demand & late, structural, 0.0)
+    excess = excess + jnp.where(valid, 0.0, structural)
     util_ref[...] = -cost - jnp.float32(INFEASIBLE_PENALTY) * excess
-    feas_ref[...] = feas.astype(jnp.int32)
+    feas_ref[...] = jnp.where(excess == 0.0, 1, 0)
 
 
 def placement_score(
@@ -101,9 +100,9 @@ def placement_score(
         in_specs=[
             pl.BlockSpec((block_r, g), lambda i: (i, 0)),
             pl.BlockSpec((block_r, g), lambda i: (i, 0)),
-            pl.BlockSpec((k, g), lambda i: (0, 0)),
-            pl.BlockSpec((k, g), lambda i: (0, 0)),
-            pl.BlockSpec((k, g), lambda i: (0, 0)),
+            pl.BlockSpec((g, k), lambda i: (0, 0)),
+            pl.BlockSpec((g, k), lambda i: (0, 0)),
+            pl.BlockSpec((g, k), lambda i: (0, 0)),
             pl.BlockSpec((2, k), lambda i: (0, 0)),
         ],
         out_specs=[
@@ -114,7 +113,7 @@ def placement_score(
             jax.ShapeDtypeStruct((r, k), jnp.float32),
             jax.ShapeDtypeStruct((r, k), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # Tiles are independent; let the compiler parallelize.
             dimension_semantics=("parallel",),
         ),
@@ -122,9 +121,9 @@ def placement_score(
     )(
         jnp.asarray(reads, jnp.float32),
         jnp.asarray(writes, jnp.float32),
-        jnp.asarray(read_price, jnp.float32),
-        jnp.asarray(write_price, jnp.float32),
-        jnp.asarray(read_rtt, jnp.float32),
+        jnp.asarray(read_price, jnp.float32).T,
+        jnp.asarray(write_price, jnp.float32).T,
+        jnp.asarray(read_rtt, jnp.float32).T,
         jnp.asarray(cand_meta, jnp.float32),
     )
 

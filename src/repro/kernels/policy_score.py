@@ -26,8 +26,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 from repro.kernels.ref import (
     INFEASIBLE_PENALTY,
     LVL_COLS,
@@ -127,7 +127,7 @@ def policy_score(
             jax.ShapeDtypeStruct((s, l), jnp.float32),
             jax.ShapeDtypeStruct((s, l), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # Tiles are independent; let the compiler parallelize.
             dimension_semantics=("parallel",),
         ),

@@ -31,8 +31,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 # ops meta columns
 CLIENT, REPLICA, RESOURCE, VALID = 0, 1, 2, 3
@@ -142,7 +142,7 @@ def session_floor(
             jax.ShapeDtypeStruct((b, OUT_COLS), jnp.int32),
             jax.ShapeDtypeStruct((n_clients, n_resources), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # The floor accumulator carries across grid steps.
             dimension_semantics=("arbitrary",),
         ),

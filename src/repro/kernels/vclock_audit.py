@@ -21,8 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 # meta columns
 CLIENT, KIND, RESOURCE, VERSION, SEQ, VALID = 0, 1, 2, 3, 4, 5
@@ -31,33 +30,39 @@ META_COLS = 8
 
 def _audit_kernel(vci_ref, vcj_ref, mi_ref, mj_ref, out_ref,
                   *, n_clients: int, delta: int):
+    # The i side is one op per sublane, the j side one op per lane, so
+    # ``i(c)`` is a (bm, 1) column, ``j(c)`` a (1, bm) row, and every
+    # pair relation a (bm, bm) broadcast — no vector transposes, and
+    # only a few (bm, bm) tiles live at once.
     vci = vci_ref[...]          # (bm, N)
-    vcj = vcj_ref[...]          # (bm, N)
+    vcj = vcj_ref[...]          # (N, bm)
     mi = mi_ref[...]            # (bm, META_COLS)
-    mj = mj_ref[...]            # (bm, META_COLS)
+    mj = mj_ref[...]            # (META_COLS, bm)
     bm = vci.shape[0]
+
+    def i(c):
+        return mi[:, c:c + 1]
+
+    def j(c):
+        return mj[c:c + 1, :]
 
     big = jnp.int32(-(2 ** 30))
     maxd = jnp.full((bm, bm), big, jnp.int32)
     mind = jnp.full((bm, bm), -big, jnp.int32)
     for n in range(n_clients):
-        diff = vci[:, n][:, None] - vcj[:, n][None, :]
+        diff = vci[:, n:n + 1] - vcj[n:n + 1, :]
         maxd = jnp.maximum(maxd, diff)
         mind = jnp.minimum(mind, diff)
     hb = jnp.logical_and(maxd <= 0, mind < 0)
 
-    def col(m, c):
-        return m[:, c]
-
-    valid = jnp.logical_and(
-        col(mi, VALID)[:, None] > 0, col(mj, VALID)[None, :] > 0)
-    same_res = col(mi, RESOURCE)[:, None] == col(mj, RESOURCE)[None, :]
-    ordered = col(mi, SEQ)[:, None] < col(mj, SEQ)[None, :]
-    same_client = col(mi, CLIENT)[:, None] == col(mj, CLIENT)[None, :]
-    ki = col(mi, KIND)[:, None]
-    kj = col(mj, KIND)[None, :]
-    vi = col(mi, VERSION)[:, None]
-    vj = col(mj, VERSION)[None, :]
+    valid = jnp.logical_and(i(VALID) > 0, j(VALID) > 0)
+    same_res = i(RESOURCE) == j(RESOURCE)
+    ordered = i(SEQ) < j(SEQ)
+    same_client = i(CLIENT) == j(CLIENT)
+    ki = i(KIND)
+    kj = j(KIND)
+    vi = i(VERSION)
+    vj = j(VERSION)
 
     base = valid & same_res & ordered
     sc = base & same_client & hb
@@ -70,23 +75,19 @@ def _audit_kernel(vci_ref, vcj_ref, mi_ref, mj_ref, out_ref,
     phase = jnp.where(base & ~same_client & hb, 5, phase)
     phase = jnp.where(base & ~hb, 6, phase)
 
-    viol = jnp.zeros((bm, bm), bool)
-    viol |= (phase == 1) & (vj < vi)
-    viol |= (phase == 2) & (vj <= vi)
-    viol |= (phase == 3) & (vj < vi)
-    viol |= (phase == 4) & (vj <= vi)
-    viol |= (phase == 5) & (ki == 1) & (kj == 0) & (vj < vi)
-
-    gap = col(mj, SEQ)[None, :] - col(mi, SEQ)[:, None]
-    timed = base & (ki == 1) & (kj == 0) & (vj < vi) & (gap > delta)
-    if delta <= 0:
-        timed = jnp.zeros_like(timed)
-
-    out_ref[...] = (
-        phase
-        | (viol.astype(jnp.int32) << 8)
-        | (timed.astype(jnp.int32) << 9)
+    viol = (
+        ((phase == 1) & (vj < vi))
+        | ((phase == 2) & (vj <= vi))
+        | ((phase == 3) & (vj < vi))
+        | ((phase == 4) & (vj <= vi))
+        | ((phase == 5) & (ki == 1) & (kj == 0) & (vj < vi))
     )
+    codes = phase | jnp.where(viol, 1 << 8, 0)
+    if delta > 0:
+        gap = j(SEQ) - i(SEQ)
+        timed = base & (ki == 1) & (kj == 0) & (vj < vi) & (gap > delta)
+        codes = codes | jnp.where(timed, 1 << 9, 0)
+    out_ref[...] = codes
 
 
 def vclock_audit(
@@ -127,14 +128,14 @@ def vclock_audit(
         grid=(nb, nb),
         in_specs=[
             pl.BlockSpec((block, n), lambda i, j: (i, 0)),
-            pl.BlockSpec((block, n), lambda i, j: (j, 0)),
+            pl.BlockSpec((n, block), lambda i, j: (0, j)),
             pl.BlockSpec((block, META_COLS), lambda i, j: (i, 0)),
-            pl.BlockSpec((block, META_COLS), lambda i, j: (j, 0)),
+            pl.BlockSpec((META_COLS, block), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block, block), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, m), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
-    )(vc, vc, meta, meta)
+    )(vc, vc.T, meta, meta.T)

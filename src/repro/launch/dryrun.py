@@ -1,7 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the
-# device count at first init).  Do not move them.
+# A CPU-only tool: it compiles for 512 host devices and must never take
+# a chip, which belongs to one process at a time.  The --all children
+# inherit this environment.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks the
+# platform and device count at first init).  Do not move them.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -398,7 +402,10 @@ def main() -> int:
                 time.sleep(1.0)
                 reap()
             print(f"[dryrun] {mk} {arch} {shape_name}", flush=True)
-            procs.append((subprocess.Popen(cmd), f"{mk}/{arch}/{shape_name}"))
+            procs.append((
+                subprocess.Popen(cmd, env={**os.environ, "JAX_PLATFORMS": "cpu"}),
+                f"{mk}/{arch}/{shape_name}",
+            ))
         while procs:
             time.sleep(1.0)
             reap()

@@ -15,27 +15,17 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """Explicit Auto axis types where the jax version supports them.
-
-    ``jax.sharding.AxisType`` only exists in newer jax; older versions
-    treat every mesh axis as Auto already, so omitting the kwarg is
-    equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh for tests/elastic rescale."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    """Arbitrary mesh for tests/elastic rescale (every axis Auto)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def n_pods(mesh) -> int:

@@ -5,9 +5,8 @@ The unified epoch engine (``repro.engine``) replaced the four batched
 shims.  This module pins their *pre-refactor* outputs: every case below
 was captured on the last commit where each wrapper still had its own
 hand-rolled loop, and ``tests/test_engine_bridge.py`` replays the cases
-through the engine and asserts the sanitized result dictionaries are
-bit-identical (ints exact, floats exact — same machine, same XLA, no
-tolerance).
+through the engine and asserts the sanitized result dictionaries
+:func:`match` (ints, bools and strings exact; floats to :data:`RTOL`).
 
 Regenerate (only when a *deliberate* metrics change lands) with::
 
@@ -19,9 +18,11 @@ which rewrites ``tests/data/golden_wrappers.json``.
 from __future__ import annotations
 
 import json
+import math
 import pathlib
 from typing import Any, Callable
 
+import jax
 import numpy as np
 
 from repro.core import availability as av
@@ -33,6 +34,13 @@ from repro.storage import simulator as sim
 from repro.storage.ycsb import PHASED_RW, WORKLOAD_A
 
 GOLDEN_PATH = pathlib.Path(__file__).parent / "data" / "golden_wrappers.json"
+
+# Float fields are float32 accumulations (per-region RTT sums, the
+# adaptive bill) whose summation order changes between XLA builds and
+# between backends: the goldens drift by up to ~1e-6 relative on a
+# newer XLA, far below any change a metric could show.  Integer fields
+# carry every count and stay exact.
+RTOL = 1e-5
 
 LEVELS = (
     ConsistencyLevel.X_STCC,
@@ -128,13 +136,40 @@ def sanitize(obj: Any) -> Any:
     return obj
 
 
+def match(got: Any, want: Any, path: str = "") -> list[str]:
+    """Paths where ``got`` differs from ``want`` (empty: they match).
+
+    Exact everywhere except floats, which agree to :data:`RTOL`."""
+    if isinstance(want, dict) and isinstance(got, dict):
+        if set(got) != set(want):
+            return [f"{path}: keys {sorted(set(got) ^ set(want))}"]
+        return [d for k in want for d in match(got[k], want[k], f"{path}/{k}")]
+    if isinstance(want, list) and isinstance(got, list):
+        if len(got) != len(want):
+            return [f"{path}: length {len(got)} != {len(want)}"]
+        return [d for i, (g, w) in enumerate(zip(got, want))
+                for d in match(g, w, f"{path}[{i}]")]
+    if isinstance(want, float) and isinstance(got, float):
+        if math.isclose(got, want, rel_tol=RTOL, abs_tol=0.0):
+            return []
+    elif type(got) is type(want) and got == want:
+        return []
+    return [f"{path}: {got!r} != {want!r}"]
+
+
 def run_case(name: str) -> Any:
     fn, kwargs = _cases()[name]
     kwargs = dict(kwargs)
     if fn is sim.run_protocol_adaptive:
         w = kwargs.pop("w")
         sla = kwargs.pop("sla")
-        return sanitize(fn(w, sla, **kwargs))
+        # Captured when ``jax_threefry_partitionable`` defaulted to
+        # False.  The new default draws different bits from the same
+        # key, which moves the controller's epsilon-exploration draws
+        # (session 0 explores at epoch 11).  Replay with the PRNG the
+        # golden was captured under.
+        with jax.threefry_partitionable(False):
+            return sanitize(fn(w, sla, **kwargs))
     level = kwargs.pop("level")
     w = kwargs.pop("w")
     return sanitize(fn(level, w, **kwargs))

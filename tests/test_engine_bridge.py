@@ -6,8 +6,10 @@ representative driver invocations captured on the commit *before* the
 (``repro.engine``) — all six consistency levels through each driver,
 plus gossip/recovery, outage, sharded-faulty, and adaptive composites.
 Each test replays one case through today's wrapper and requires the
-sanitized result to be **equal**, not approximately equal: the engine
-refactor is a pure reorganization, and any numeric drift is a bug.
+sanitized result to match: every integer exactly, every float to
+``golden_bridge.RTOL`` (float32 sums reorder between XLA builds and
+backends).  The engine refactor is a pure reorganization, and any
+drift in a count is a bug.
 
 The golden file is an artifact, not derived state — regenerating it
 against current code would turn this gate into a tautology.  It should
@@ -30,4 +32,4 @@ def test_wrapper_bit_identical(name):
         "on a trusted commit via tests/golden_bridge.py"
     )
     got = golden_bridge.run_case(name)
-    assert got == GOLDEN[name]
+    assert golden_bridge.match(got, GOLDEN[name]) == []

@@ -171,7 +171,7 @@ def test_obs_on_matches_golden_wrapper_traces(name):
     level, w = kwargs.pop("level"), kwargs.pop("w")
     got = golden_bridge.sanitize(fn(level, w, obs=ObsConfig(), **kwargs))
     obs = got.pop("obs")
-    assert got == GOLDEN[name]
+    assert golden_bridge.match(got, GOLDEN[name]) == []
     m = obs["metrics"]
     assert m["staleness_age"]["count"] == obs["counters"]["reads"]
     if name.startswith("faulty/X_STCC/outage"):

@@ -325,11 +325,18 @@ def test_sharded_runner_uses_device_mesh():
         "                         use_devices=True, **kw);"
         "b = run_protocol_sharded(ConsistencyLevel.X_STCC, WORKLOAD_A,"
         "                         use_devices=False, **kw);"
-        "assert a == b, (a, b); print('mesh OK')"
+        "assert a == b, (a, b);"
+        "from repro.engine import EngineConfig, EpochEngine;"
+        "lay = EpochEngine(EngineConfig(level=ConsistencyLevel.X_STCC,"
+        "    **kw)).replay(WORKLOAD_A)['layout'];"
+        "assert lay == {'mode': 'shard_map', 'devices': 2}, lay;"
+        "print('mesh OK')"
     )
     src = str(pathlib.Path(__file__).resolve().parent.parent / "src")
     env = dict(os.environ)
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    # The child must never take an accelerator from the test process.
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
         timeout=300, env=env,
