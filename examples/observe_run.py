@@ -3,12 +3,19 @@
 The observability plane measures without re-introducing host→device
 round trips: the staleness/severity/latency/queue-depth distributions
 accumulate *inside* the engine's scan carry (one jit entry, same as an
-unobserved run), and host-side span tracing wraps the lifecycle around
-it.  This example runs the crash-recovery geometry — faults + gossip +
-hinted handoff on a geo topology — with ``obs=ObsConfig()``, exports
-the Chrome trace (open ``chrome://tracing`` or Perfetto on the written
-JSON), and renders the percentile/cost report two consistency levels
-side by side.
+unobserved run), and the engine's ``engine.*`` spans mark its host work
+around it.  This example runs the crash-recovery geometry — faults +
+gossip + hinted handoff on a geo topology — with ``obs=ObsConfig()``
+under a recording ``Tracer``, exports the Chrome trace (open it in
+Perfetto, ui.perfetto.dev, or ``chrome://tracing``), and renders the
+percentile/cost report two consistency levels side by side.
+
+The same spans land in a JAX profile, beside what the device ran, with
+the round step's stage names on the device ops::
+
+    jax.profiler.start_trace("/tmp/replay-profile")
+    EpochEngine(config).run(WORKLOAD_A)
+    jax.profiler.stop_trace()
 
 Run:  PYTHONPATH=src python examples/observe_run.py
 """
@@ -57,17 +64,13 @@ def main() -> None:
         runs[level.value] = result
         trace_path = out / f"trace_{level.value}.json"
         tracer.write_chrome(trace_path)
-        spans = {
-            e["name"]: e["dur"] / 1e3
-            for e in tracer.events if e["ph"] == "X"
-        }
-        (entries,) = [
-            e["args"]["count"] for e in tracer.events
-            if e["name"] == "jit_entries"
-        ]
-        print(f"--- {level.value}: jit entries = {entries}")
-        for name in ("prepare", "compile", "execute", "assemble"):
-            print(f"    {name:<9} {spans[name]:9.1f} ms")
+        (launch,) = tracer.spans("engine.launch")
+        print(f"--- {level.value}: jit entries = "
+              f"{launch['args']['jit_entries']}, compiles at launch = "
+              f"{launch['args']['compiles']}")
+        for name in ("engine.prepare", "engine.launch", "engine.assemble"):
+            (span,) = tracer.spans(name)
+            print(f"    {name:<16} {span['dur'] / 1e3:9.1f} ms")
         print(f"    trace -> {trace_path}")
 
     artifact = out / "runs.json"

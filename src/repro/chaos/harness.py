@@ -21,12 +21,15 @@ distributions device-side, and ``tracer=Tracer()`` to get the
 experiment as a timeline: every nemesis action (crash/outage/partition
 epochs), each invariant's verdict, and — with obs on — the per-epoch
 violation counts, including the **first violating epoch**, land as
-trace instants, so a failed chaos run pinpoints *when* it went wrong
-instead of reporting one pass/fail bit.
+trace instants beside the spans of the run, its twin and the quiesce
+(each holding the engine's own ``engine.*`` spans), so a failed chaos
+run pinpoints *when* it went wrong instead of reporting one pass/fail
+bit.
 """
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from typing import Any
 
 import jax.numpy as jnp
@@ -38,6 +41,7 @@ from repro.core.consistency import ConsistencyLevel
 from repro.core.replicated_store import DurabilityConfig
 from repro.gossip import GossipConfig
 from repro.obs.metrics import ObsConfig
+from repro.obs.trace import span
 from repro.storage.simulator import run_protocol_faulty
 from repro.storage.ycsb import WORKLOAD_A, Workload
 
@@ -119,8 +123,6 @@ def run_chaos(
     records nemesis actions, per-epoch violation counts, and each
     invariant's outcome as trace events.
     """
-    from contextlib import nullcontext
-
     n_epochs = n_ops // batch_size + (1 if n_ops % batch_size else 0)
     schedule = random_schedule(
         n_epochs, n_replicas, seed=seed, p_crash=p_crash,
@@ -136,40 +138,39 @@ def run_chaos(
             cadence=gossip.cadence if gossip is not None else 0,
         )
         _trace_nemesis(tracer, schedule)
-    span = tracer.span if tracer is not None else (
-        lambda name, **a: nullcontext()
-    )
     kw = dict(
         n_ops=n_ops, batch_size=batch_size, schedule=schedule,
         recovery=recovery, gossip=gossip, audit=True, obs=obs,
         _return_state=True,
     )
-    with span("chaos.run", seed=seed):
-        res = run_protocol_faulty(level, w, **kw)
-    twin_kw = dict(kw, schedule=schedule.strip_crashes(), obs=None)
-    with span("chaos.twin", seed=seed):
-        twin = run_protocol_faulty(level, w, **twin_kw)
+    recording = tracer.recording() if tracer is not None else nullcontext()
+    with recording:
+        with span("chaos.run", seed=seed):
+            res = run_protocol_faulty(level, w, **kw)
+        twin_kw = dict(kw, schedule=schedule.strip_crashes(), obs=None)
+        with span("chaos.twin", seed=seed):
+            twin = run_protocol_faulty(level, w, **twin_kw)
 
-    crashed = schedule.has_crashes
-    breaches = check_invariants(res, level, crashed=crashed)
+        crashed = schedule.has_crashes
+        breaches = check_invariants(res, level, crashed=crashed)
 
-    first_violation = None
-    if obs is not None and obs.enabled:
-        ob = res["obs"]
-        first_violation = ob.get("first_violation_epoch")
-        if tracer is not None:
-            for t, v in enumerate(ob["per_round"]["viol"]):
-                if v:
-                    tracer.instant(
-                        "invariant.violations", epoch=t, count=int(v)
-                    )
+        first_violation = None
+        if obs is not None and obs.enabled:
+            ob = res["obs"]
+            first_violation = ob.get("first_violation_epoch")
+            if tracer is not None:
+                for t, v in enumerate(ob["per_round"]["viol"]):
+                    if v:
+                        tracer.instant(
+                            "invariant.violations", epoch=t, count=int(v)
+                        )
 
-    store = res["_store"]
-    with span("chaos.quiesce"):
-        sig = _fleet_signature(_quiesce(store, res["_state"]))
-        twin_sig = _fleet_signature(
-            _quiesce(twin["_store"], twin["_state"])
-        )
+        store = res["_store"]
+        with span("chaos.quiesce"):
+            sig = _fleet_signature(_quiesce(store, res["_state"]))
+            twin_sig = _fleet_signature(
+                _quiesce(twin["_store"], twin["_state"])
+            )
     diverged = [
         k for k in sig if not np.array_equal(sig[k], twin_sig[k])
     ]

@@ -402,22 +402,24 @@ class ReplicatedStore:
         )
         pend_apply = state.pend_apply
         if new_pend_apply is not None:
-            pend_apply = pend_apply.at[res.slot].set(
-                new_pend_apply, mode="drop"
-            )
+            with jax.named_scope("pending_ring"):
+                pend_apply = pend_apply.at[res.slot].set(
+                    new_pend_apply, mode="drop"
+                )
         duot = state.duot
         if record:
-            duot = duot_lib.record(
-                duot,
-                {
-                    "client": c,
-                    "kind": k,
-                    "resource": r,
-                    "version": res.version,
-                    "replica": p,
-                    "vc": res.vc,
-                },
-            )
+            with jax.named_scope("duot_record"):
+                duot = duot_lib.record(
+                    duot,
+                    {
+                        "client": c,
+                        "kind": k,
+                        "resource": r,
+                        "version": res.version,
+                        "replica": p,
+                        "vc": res.vc,
+                    },
+                )
         return (
             StoreState(cluster=res.state, duot=duot,
                        pend_apply=pend_apply, hints=state.hints,

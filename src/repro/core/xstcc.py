@@ -316,20 +316,27 @@ def apply_op_batch(
             pend_live=state.pend_live,
             pend_apply=jnp.asarray(pend_apply, jnp.int32),
         )
-    occ, raw, floor = kernel_ops.op_ingest(
-        c, p, r, is_w,
-        state.global_version[r],
-        state.replica_version[p, r],
-        jnp.maximum(state.read_floor[c, r], state.write_floor[c, r]),
-        op_index=op_index,
-        apply_index=apply_index,
-        impl="dense" if ingest is None else ingest,
-        n_clients=state.session_vc.shape[0],
-        n_replicas=state.replica_version.shape[0],
-        n_resources=state.replica_version.shape[1],
-        **pend_kwargs,
-    )
-    gcur = state.global_version[r] + occ         # global version seen by op i
+    # The stages below carry ``jax.named_scope`` names, which reach the
+    # device trace as the ops' name stacks: ``floors`` (the reads and
+    # writes of the (C, R) floors and the (P, R) / (R,) versions),
+    # ``op_ingest`` (the prefix kernel), ``vclock_scan`` (the clock
+    # chain) and ``pending_ring`` (slot assignment).
+    with jax.named_scope("floors"):
+        g0 = state.global_version[r]
+        raw0 = state.replica_version[p, r]
+        floor0 = jnp.maximum(state.read_floor[c, r], state.write_floor[c, r])
+    with jax.named_scope("op_ingest"):
+        occ, raw, floor = kernel_ops.op_ingest(
+            c, p, r, is_w, g0, raw0, floor0,
+            op_index=op_index,
+            apply_index=apply_index,
+            impl="dense" if ingest is None else ingest,
+            n_clients=state.session_vc.shape[0],
+            n_replicas=state.replica_version.shape[0],
+            n_resources=state.replica_version.shape[1],
+            **pend_kwargs,
+        )
+    gcur = g0 + occ                              # global version seen by op i
     ver_w = gcur + 1                             # version created IF a write
     verw_masked = jnp.where(is_w, ver_w, 0)
 
@@ -354,10 +361,11 @@ def apply_op_batch(
         # Unrolling amortizes the scan's per-step loop overhead — the
         # body is ~tens of scalar ops on two small rows, far below the
         # iteration cost of an un-unrolled lax.scan on CPU.
-        (session_vc, replica_vc), vcs = jax.lax.scan(
-            clock_step, (state.session_vc, state.replica_vc), (c, p, is_w),
-            unroll=8,
-        )
+        with jax.named_scope("vclock_scan"):
+            (session_vc, replica_vc), vcs = jax.lax.scan(
+                clock_step, (state.session_vc, state.replica_vc),
+                (c, p, is_w), unroll=8,
+            )
     else:
         session_vc = state.session_vc
         replica_vc = state.replica_vc
@@ -367,45 +375,56 @@ def apply_op_batch(
     # The k-th-free-slot map is a cumsum rank + scatter (O(Q)), not an
     # argsort (O(Q log Q)): free slot q has rank cumsum(free)[q] - 1
     # among the free slots, so scattering q to its rank inverts the map.
-    free = jnp.logical_not(state.pend_live)
-    n_free = jnp.sum(free.astype(jnp.int32))
-    wrank = jnp.cumsum(is_w.astype(jnp.int32)) - 1
-    free_rank = jnp.cumsum(free.astype(jnp.int32)) - 1
-    kth_free = (
-        jnp.zeros((Q,), jnp.int32)
-        .at[jnp.where(free, free_rank, Q)]
-        .set(jnp.arange(Q, dtype=jnp.int32), mode="drop")
-    )
-    enq = is_w & (wrank < n_free)
-    slot = jnp.where(
-        enq, kth_free[jnp.clip(wrank, 0, Q - 1)], jnp.int32(Q)
-    )
-    dropped = is_w & jnp.logical_not(enq)
-    applied0 = jnp.arange(P, dtype=jnp.int32)[None, :] == p[:, None]
-    pend_time = state.clock + idx
+    with jax.named_scope("pending_ring"):
+        free = jnp.logical_not(state.pend_live)
+        n_free = jnp.sum(free.astype(jnp.int32))
+        wrank = jnp.cumsum(is_w.astype(jnp.int32)) - 1
+        free_rank = jnp.cumsum(free.astype(jnp.int32)) - 1
+        kth_free = (
+            jnp.zeros((Q,), jnp.int32)
+            .at[jnp.where(free, free_rank, Q)]
+            .set(jnp.arange(Q, dtype=jnp.int32), mode="drop")
+        )
+        enq = is_w & (wrank < n_free)
+        slot = jnp.where(
+            enq, kth_free[jnp.clip(wrank, 0, Q - 1)], jnp.int32(Q)
+        )
+        dropped = is_w & jnp.logical_not(enq)
+        applied0 = jnp.arange(P, dtype=jnp.int32)[None, :] == p[:, None]
+        pend_time = state.clock + idx
+        ring = dict(
+            pend_client=state.pend_client.at[slot].set(c, mode="drop"),
+            pend_resource=state.pend_resource.at[slot].set(r, mode="drop"),
+            pend_version=state.pend_version.at[slot].set(ver_w, mode="drop"),
+            pend_vc=(state.pend_vc.at[slot].set(vcs, mode="drop")
+                     if with_clocks else state.pend_vc),
+            pend_coord=state.pend_coord.at[slot].set(p, mode="drop"),
+            pend_time=state.pend_time.at[slot].set(pend_time, mode="drop"),
+            pend_live=state.pend_live.at[slot].set(True, mode="drop"),
+            pend_applied=state.pend_applied.at[slot].set(
+                applied0, mode="drop"
+            ),
+            pend_dropped=_saturating_add(
+                state.pend_dropped, jnp.sum(dropped.astype(jnp.int32))
+            ),
+        )
+
+    with jax.named_scope("floors"):
+        floors = dict(
+            replica_version=state.replica_version.at[p, r].max(verw_masked),
+            read_floor=state.read_floor.at[c, r].max(
+                jnp.where(is_w, ver_w, served)
+            ),
+            write_floor=state.write_floor.at[c, r].max(verw_masked),
+            global_version=state.global_version.at[r].max(verw_masked),
+        )
 
     new = state._replace(
-        replica_version=state.replica_version.at[p, r].max(verw_masked),
         replica_vc=replica_vc,
         session_vc=session_vc,
-        read_floor=state.read_floor.at[c, r].max(
-            jnp.where(is_w, ver_w, served)
-        ),
-        write_floor=state.write_floor.at[c, r].max(verw_masked),
-        global_version=state.global_version.at[r].max(verw_masked),
-        pend_client=state.pend_client.at[slot].set(c, mode="drop"),
-        pend_resource=state.pend_resource.at[slot].set(r, mode="drop"),
-        pend_version=state.pend_version.at[slot].set(ver_w, mode="drop"),
-        pend_vc=(state.pend_vc.at[slot].set(vcs, mode="drop")
-                 if with_clocks else state.pend_vc),
-        pend_coord=state.pend_coord.at[slot].set(p, mode="drop"),
-        pend_time=state.pend_time.at[slot].set(pend_time, mode="drop"),
-        pend_live=state.pend_live.at[slot].set(True, mode="drop"),
-        pend_applied=state.pend_applied.at[slot].set(applied0, mode="drop"),
-        pend_dropped=_saturating_add(
-            state.pend_dropped, jnp.sum(dropped.astype(jnp.int32))
-        ),
         clock=state.clock + B,
+        **floors,
+        **ring,
     )
     return BatchResult(
         state=new, version=version_out, vc=vcs, admissible=admissible,

@@ -35,6 +35,7 @@ from repro.engine import stream as stream_lib
 from repro.gossip.scheduler import GossipConfig, gossip_pairs
 from repro.kernels import ops as kernel_ops
 from repro.obs import metrics as obs_lib
+from repro.obs import trace as trace_lib
 
 # Monotone counter of jit re-entries into compiled replays — the
 # "host hops per replay" the protocol bench reports.  One replay = one
@@ -143,319 +144,336 @@ def unified_runner(
             # Crash epoch: the replica's volatile state dies *before*
             # anything else happens this epoch; what survives is the
             # store's durability layer (snapshot + WAL).
-            def do_crash(s):
-                return store.crash(s, ops["crash"])
+            with jax.named_scope("crash"):
+                def do_crash(s):
+                    return store.crash(s, ops["crash"])
 
-            def no_crash(s):
-                z = jnp.int32(0)
-                return s, {"wal_replayed": z, "snap_read": z,
-                           "rows_lost": z}
+                def no_crash(s):
+                    z = jnp.int32(0)
+                    return s, {"wal_replayed": z, "snap_read": z,
+                               "rows_lost": z}
 
-            st, cinfo = jax.lax.cond(
-                ops["crash"].any(), do_crash, no_crash, st
-            )
-            rx = carry["rx"]
-            rx = {
-                **rx,
-                "crashes": rx["crashes"]
-                + jnp.sum(ops["crash"].astype(jnp.int32)),
-                "wal_replayed": rx["wal_replayed"] + cinfo["wal_replayed"],
-                "rows_lost": rx["rows_lost"] + cinfo["rows_lost"],
-                "snap_read": rx["snap_read"] + cinfo["snap_read"],
-            }
-
-            # Rejoin epoch: pull stale ranges from the nearest live
-            # holder before the replica serves anything.
-            def do_boot(s):
-                s2, tel = store.bootstrap(
-                    s, targets=ops["rejoin"], up=up, link=conn,
-                    n_ranges=boot_ranges, impl=boot_impl,
+                st, cinfo = jax.lax.cond(
+                    ops["crash"].any(), do_crash, no_crash, st
                 )
-                return s2, (
-                    jnp.sum(tel["cells"]), jnp.sum(tel["pend"]),
-                    jnp.sum(tel["valid"].astype(jnp.int32)),
+                rx = carry["rx"]
+                rx = {
+                    **rx,
+                    "crashes": rx["crashes"]
+                    + jnp.sum(ops["crash"].astype(jnp.int32)),
+                    "wal_replayed": rx["wal_replayed"] + cinfo["wal_replayed"],
+                    "rows_lost": rx["rows_lost"] + cinfo["rows_lost"],
+                    "snap_read": rx["snap_read"] + cinfo["snap_read"],
+                }
+
+            with jax.named_scope("bootstrap"):
+                # Rejoin epoch: pull stale ranges from the nearest live
+                # holder before the replica serves anything.
+                def do_boot(s):
+                    s2, tel = store.bootstrap(
+                        s, targets=ops["rejoin"], up=up, link=conn,
+                        n_ranges=boot_ranges, impl=boot_impl,
+                    )
+                    return s2, (
+                        jnp.sum(tel["cells"]), jnp.sum(tel["pend"]),
+                        jnp.sum(tel["valid"].astype(jnp.int32)),
+                    )
+
+                def no_boot(s):
+                    z = jnp.int32(0)
+                    return s, (z, z, z)
+
+                st, (bc, bp, be) = jax.lax.cond(
+                    ops["rejoin"].any(), do_boot, no_boot, st
                 )
-
-            def no_boot(s):
-                z = jnp.int32(0)
-                return s, (z, z, z)
-
-            st, (bc, bp, be) = jax.lax.cond(
-                ops["rejoin"].any(), do_boot, no_boot, st
-            )
-            rx = {
-                **rx,
-                "boot_cells": rx["boot_cells"] + bc,
-                "boot_pend": rx["boot_pend"] + bp,
-                "boot_events": rx["boot_events"] + be,
-            }
-            carry = {**carry, "rx": rx}
+                rx = {
+                    **rx,
+                    "boot_cells": rx["boot_cells"] + bc,
+                    "boot_pend": rx["boot_pend"] + bp,
+                    "boot_events": rx["boot_events"] + be,
+                }
+                carry = {**carry, "rx": rx}
         if w_on:
-            # Applied copies at the start of the epoch (post-recovery):
-            # the epoch's growth is what each replica journals.
-            applied0 = jnp.sum(
-                st.cluster.pend_applied.astype(jnp.int32), axis=0
-            )
+            with jax.named_scope("durability"):
+                # Applied copies at the start of the epoch (post-recovery):
+                # the epoch's growth is what each replica journals.
+                applied0 = jnp.sum(
+                    st.cluster.pend_applied.astype(jnp.int32), axis=0
+                )
         if h_on:
-            # Heal epoch: targeted hint deliveries front-run the full
-            # anti-entropy pass — drained hints shrink its backlog.
-            st, hd = jax.lax.cond(
-                ops["heal"],
-                lambda s: store.drain_hints(s, up=up, link=conn),
-                lambda s: (s, jnp.zeros((P,), jnp.int32)),
-                st,
-            )
+            with jax.named_scope("hint_drain"):
+                # Heal epoch: targeted hint deliveries front-run the full
+                # anti-entropy pass — drained hints shrink its backlog.
+                st, hd = jax.lax.cond(
+                    ops["heal"],
+                    lambda s: store.drain_hints(s, up=up, link=conn),
+                    lambda s: (s, jnp.zeros((P,), jnp.int32)),
+                    st,
+                )
         if faults_on:
-            # Heal epoch: reconcile the backlog along the newly-available
-            # links (Δ=0 full catch-up) before serving this epoch's ops.
-            st, ev = jax.lax.cond(
-                ops["heal"],
-                lambda s: store.anti_entropy(s, up=up, link=conn),
-                lambda s: (s, jnp.int32(0)),
-                st,
-            )
-            # Ops whose home replica is down fail over to the next live
-            # replica in ring order (the serving router's failover).
-            home = avail_lib.reroute_ops(ops["home"], up)
-            carry = {
-                **carry,
-                "ae": carry["ae"] + ev,
-                "fail": carry["fail"]
-                + jnp.sum((home != ops["home"]).astype(jnp.int32)),
-            }
-            # While a fault is active, the closed-form cadence's
-            # "applied everywhere at the apply index" assumption is
-            # wrong — defer pending-ring visibility to the real masked
-            # merges.
-            end = step0 + width
-            st = st._replace(pend_apply=jnp.where(
-                ops["faulty"], jnp.maximum(st.pend_apply, end),
-                st.pend_apply,
-            ))
-        else:
-            home = ops["home"]
-        if w_on:
-            # Ring slots claimed by this batch's writes overwrite their
-            # old applied bits; snapshot them so the epoch's journal
-            # growth counts every applied copy, not the net of the sum.
-            pre_bits = st.cluster.pend_applied
-        # -- op ingest (the fused kernel chain, device-side) ------------
-        st, res = store.apply_batch(
-            st, client=ops["client"], replica=home,
-            resource=ops["resource"], kind=ops["kind"],
-            op_step0=step0 if emulate else None,
-            apply_index=ops.get("apply_idx"),
-            record=not (lean or telemetry),
-            with_clocks=not lean_merge,
-        )
-        if h_on:
-            # Writes served during a fault leave hints for the replicas
-            # the coordinator could not reach this epoch.
-            def enq(s):
-                return store.enqueue_hints(
-                    s, slot=res.slot, version=res.version,
-                    kind=ops["kind"], home=home, conn=conn,
+            with jax.named_scope("anti_entropy"):
+                # Heal epoch: reconcile the backlog along the newly-available
+                # links (Δ=0 full catch-up) before serving this epoch's ops.
+                st, ev = jax.lax.cond(
+                    ops["heal"],
+                    lambda s: store.anti_entropy(s, up=up, link=conn),
+                    lambda s: (s, jnp.int32(0)),
+                    st,
                 )
-
-            z = jnp.int32(0)
-            st, ne, nd = jax.lax.cond(
-                ops["faulty"], enq, lambda s: (s, z, z), st
+        # -- op ingest (the fused kernel chain, device-side) ------------
+        with jax.named_scope("apply"):
+            if faults_on:
+                # Ops whose home replica is down fail over to the next
+                # live replica in ring order (the serving router's
+                # failover).
+                home = avail_lib.reroute_ops(ops["home"], up)
+                carry = {
+                    **carry,
+                    "ae": carry["ae"] + ev,
+                    "fail": carry["fail"]
+                    + jnp.sum((home != ops["home"]).astype(jnp.int32)),
+                }
+                # While a fault is active, the closed-form cadence's
+                # "applied everywhere at the apply index" assumption is
+                # wrong — defer pending-ring visibility to the real
+                # masked merges.
+                end = step0 + width
+                st = st._replace(pend_apply=jnp.where(
+                    ops["faulty"], jnp.maximum(st.pend_apply, end),
+                    st.pend_apply,
+                ))
+            else:
+                home = ops["home"]
+            if w_on:
+                # Ring slots claimed by this batch's writes overwrite
+                # their old applied bits; snapshot them so the epoch's
+                # journal growth counts every applied copy, not the net
+                # of the sum.
+                pre_bits = st.cluster.pend_applied
+            st, res = store.apply_batch(
+                st, client=ops["client"], replica=home,
+                resource=ops["resource"], kind=ops["kind"],
+                op_step0=step0 if emulate else None,
+                apply_index=ops.get("apply_idx"),
+                record=not (lean or telemetry),
+                with_clocks=not lean_merge,
             )
+            if h_on:
+                # Writes served during a fault leave hints for the
+                # replicas the coordinator could not reach this epoch.
+                def enq(s):
+                    return store.enqueue_hints(
+                        s, slot=res.slot, version=res.version,
+                        kind=ops["kind"], home=home, conn=conn,
+                    )
+
+                z = jnp.int32(0)
+                st, ne, nd = jax.lax.cond(
+                    ops["faulty"], enq, lambda s: (s, z, z), st
+                )
         # -- boundary merge (fixpoint / two-tier / schedule-faithful) ---
-        if lean_merge:
-            st, _ = store.merge(st, timed_only=True, boundary=step0 + width)
-        elif geo_on and faults_on:
-            before = jnp.sum(st.cluster.pend_applied.astype(jnp.int32))
-            st, _, tr = store.merge_geo(st, topology, up=up, link=conn)
-            ev = jnp.sum(st.cluster.pend_applied.astype(jnp.int32)) - before
-            carry = {**carry, "prop": carry["prop"] + ev,
-                     "traffic": carry["traffic"] + tr}
-        elif geo_on:
-            st, _, tr = store.merge_geo(st, topology)
-            carry = {**carry, "traffic": carry["traffic"] + tr}
-        elif faults_on:
-            st, _, ev = store.merge_faulty(st, up=up, link=conn)
-            carry = {**carry, "prop": carry["prop"] + ev}
-        else:
-            st, _ = store.merge(st)
+        with jax.named_scope("boundary_merge"):
+            if lean_merge:
+                st, _ = store.merge(
+                    st, timed_only=True, boundary=step0 + width
+                )
+            elif geo_on and faults_on:
+                before = jnp.sum(st.cluster.pend_applied.astype(jnp.int32))
+                st, _, tr = store.merge_geo(st, topology, up=up, link=conn)
+                ev = (jnp.sum(st.cluster.pend_applied.astype(jnp.int32))
+                      - before)
+                carry = {**carry, "prop": carry["prop"] + ev,
+                         "traffic": carry["traffic"] + tr}
+            elif geo_on:
+                st, _, tr = store.merge_geo(st, topology)
+                carry = {**carry, "traffic": carry["traffic"] + tr}
+            elif faults_on:
+                st, _, ev = store.merge_faulty(st, up=up, link=conn)
+                carry = {**carry, "prop": carry["prop"] + ev}
+            else:
+                st, _ = store.merge(st)
         # -- gossip anti-entropy ----------------------------------------
         ys = {}
-        if gx_on:
-            # Scheduled digest exchange: diff range digests with the
-            # epoch's peers, repair only the stale ranges.
-            def do_gossip(s):
-                s2, tel = store.gossip_round(
-                    s, pairs=ops["pairs"], up=up, link=conn,
-                    n_ranges=gossip.n_ranges, impl=gossip.impl,
-                )
-                return s2, (
-                    jnp.sum(tel["growth"]),
-                    jnp.sum(tel["ranges"]),
-                    jnp.sum(tel["valid"].astype(jnp.int32)),
-                    tel["gap_repaired"],
-                )
+        with jax.named_scope("gossip"):
+            if gx_on:
+                # Scheduled digest exchange: diff range digests with the
+                # epoch's peers, repair only the stale ranges.
+                def do_gossip(s):
+                    s2, tel = store.gossip_round(
+                        s, pairs=ops["pairs"], up=up, link=conn,
+                        n_ranges=gossip.n_ranges, impl=gossip.impl,
+                    )
+                    return s2, (
+                        jnp.sum(tel["growth"]),
+                        jnp.sum(tel["ranges"]),
+                        jnp.sum(tel["valid"].astype(jnp.int32)),
+                        tel["gap_repaired"],
+                    )
 
-            def no_gossip(s):
-                z = jnp.int32(0)
-                return s, (z, z, z, z)
+                def no_gossip(s):
+                    z = jnp.int32(0)
+                    return s, (z, z, z, z)
 
-            if g_on:
-                st, (gd, gr, gp, gg) = jax.lax.cond(
-                    ops["gossip"], do_gossip, no_gossip, st
+                if g_on:
+                    st, (gd, gr, gp, gg) = jax.lax.cond(
+                        ops["gossip"], do_gossip, no_gossip, st
+                    )
+                else:
+                    gd = gr = gp = gg = jnp.int32(0)
+                gx = carry["gx"]
+                gx = {
+                    **gx,
+                    "deliv": gx["deliv"] + gd,
+                    "ranges": gx["ranges"] + gr,
+                    "pairs": gx["pairs"] + gp,
+                    "gap": gx["gap"] + gg,
+                }
+                if h_on:
+                    gx = {**gx, "h_enq": gx["h_enq"] + ne,
+                          "h_drop": gx["h_drop"] + nd,
+                          "h_deliv": gx["h_deliv"] + hd}
+                carry = {**carry, "gx": gx}
+                ys["gossip"] = (gd, gr, gg)
+            elif ggx_on:
+                # Geo flavor: repair deliveries and digest payloads are
+                # attributed to the exchanging replicas' *region pair*.
+                def do_gossip_geo(s):
+                    s2, tel = store.gossip_round(
+                        s, pairs=ops["pairs"], up=all_up, link=all_conn,
+                        n_ranges=gossip.n_ranges, impl=gossip.impl,
+                    )
+                    a, b = ops["pairs"][:, 0], ops["pairs"][:, 1]
+                    ra, rb = replica_reg[a], replica_reg[b]
+                    mi = jnp.arange(a.shape[0])
+                    growth = tel["growth"]
+                    v = tel["valid"].astype(jnp.int32)
+                    zgg = jnp.zeros((G, G), jnp.int32)
+                    gt = zgg.at[ra, rb].add(growth[mi, b])
+                    gt = gt.at[rb, ra].add(growth[mi, a])
+                    dg = zgg.at[ra, rb].add(v).at[rb, ra].add(v)
+                    return s2, (gt, dg, jnp.sum(tel["ranges"]),
+                                tel["gap_repaired"])
+
+                def no_gossip_geo(s):
+                    zgg = jnp.zeros((G, G), jnp.int32)
+                    return s, (zgg, zgg, jnp.int32(0), jnp.int32(0))
+
+                st, (gt, dg, gr, gg) = jax.lax.cond(
+                    ops["gossip"], do_gossip_geo, no_gossip_geo, st
                 )
-            else:
-                gd = gr = gp = gg = jnp.int32(0)
-            gx = carry["gx"]
-            gx = {
-                **gx,
-                "deliv": gx["deliv"] + gd,
-                "ranges": gx["ranges"] + gr,
-                "pairs": gx["pairs"] + gp,
-                "gap": gx["gap"] + gg,
-            }
-            if h_on:
-                gx = {**gx, "h_enq": gx["h_enq"] + ne,
-                      "h_drop": gx["h_drop"] + nd,
-                      "h_deliv": gx["h_deliv"] + hd}
-            carry = {**carry, "gx": gx}
-            ys["gossip"] = (gd, gr, gg)
-        elif ggx_on:
-            # Geo flavor: repair deliveries and digest payloads are
-            # attributed to the exchanging replicas' *region pair*.
-            def do_gossip_geo(s):
-                s2, tel = store.gossip_round(
-                    s, pairs=ops["pairs"], up=all_up, link=all_conn,
-                    n_ranges=gossip.n_ranges, impl=gossip.impl,
-                )
-                a, b = ops["pairs"][:, 0], ops["pairs"][:, 1]
-                ra, rb = replica_reg[a], replica_reg[b]
-                mi = jnp.arange(a.shape[0])
-                growth = tel["growth"]
-                v = tel["valid"].astype(jnp.int32)
-                zgg = jnp.zeros((G, G), jnp.int32)
-                gt = zgg.at[ra, rb].add(growth[mi, b])
-                gt = gt.at[rb, ra].add(growth[mi, a])
-                dg = zgg.at[ra, rb].add(v).at[rb, ra].add(v)
-                return s2, (gt, dg, jnp.sum(tel["ranges"]),
-                            tel["gap_repaired"])
-
-            def no_gossip_geo(s):
-                zgg = jnp.zeros((G, G), jnp.int32)
-                return s, (zgg, zgg, jnp.int32(0), jnp.int32(0))
-
-            st, (gt, dg, gr, gg) = jax.lax.cond(
-                ops["gossip"], do_gossip_geo, no_gossip_geo, st
-            )
-            ggx = carry["ggx"]
-            carry = {**carry, "ggx": {
-                "traffic": ggx["traffic"] + gt,
-                "digest": ggx["digest"] + dg,
-                "ranges": ggx["ranges"] + gr,
-                "gap": ggx["gap"] + gg,
-            }}
+                ggx = carry["ggx"]
+                carry = {**carry, "ggx": {
+                    "traffic": ggx["traffic"] + gt,
+                    "digest": ggx["digest"] + dg,
+                    "ranges": ggx["ranges"] + gr,
+                    "gap": ggx["gap"] + gg,
+                }}
         # -- durability epilogue ----------------------------------------
-        if w_on:
-            # Journal each replica's applied deltas for this epoch (new
-            # coordinator copies + merge/gossip deliveries).  Recycled
-            # slots destroyed their applied bits mid-epoch; add those
-            # back so the journal measures gross applies, not the net
-            # movement of the column sums.
-            is_w = ops["kind"] == duot_lib.WRITE
-            lost = jnp.sum(
-                pre_bits[res.slot].astype(jnp.int32)
-                * is_w[:, None].astype(jnp.int32),
-                axis=0,
-            )
-            growth = jnp.maximum(
-                jnp.sum(st.cluster.pend_applied.astype(jnp.int32), axis=0)
-                - applied0 + lost, 0,
-            )
-            st = store.wal_append(st, growth)
-        if s_on:
-            # Periodic snapshot marker: persist applied state, truncate
-            # the journals (cells billed via DuraState.snap_rows).
-            st = jax.lax.cond(
-                ops["snap"],
-                lambda s: store.snapshot(s)[0],
-                lambda s: s,
-                st,
-            )
+        with jax.named_scope("durability"):
+            if w_on:
+                # Journal each replica's applied deltas for this epoch (new
+                # coordinator copies + merge/gossip deliveries).  Recycled
+                # slots destroyed their applied bits mid-epoch; add those
+                # back so the journal measures gross applies, not the net
+                # movement of the column sums.
+                is_w = ops["kind"] == duot_lib.WRITE
+                lost = jnp.sum(
+                    pre_bits[res.slot].astype(jnp.int32)
+                    * is_w[:, None].astype(jnp.int32),
+                    axis=0,
+                )
+                growth = jnp.maximum(
+                    jnp.sum(st.cluster.pend_applied.astype(jnp.int32), axis=0)
+                    - applied0 + lost, 0,
+                )
+                st = store.wal_append(st, growth)
+            if s_on:
+                # Periodic snapshot marker: persist applied state, truncate
+                # the journals (cells billed via DuraState.snap_rows).
+                st = jax.lax.cond(
+                    ops["snap"],
+                    lambda s: store.snapshot(s)[0],
+                    lambda s: s,
+                    st,
+                )
         # -- telemetry --------------------------------------------------
-        is_read = ops["kind"] == duot_lib.READ
-        if telemetry:
-            c = ops["client"]
-            z = jnp.zeros((n_clients,), jnp.int32)
-            ys["tel"] = (
-                z.at[c].add(res.stale.astype(jnp.int32)),
-                z.at[c].add(res.violation.astype(jnp.int32)),
-                z.at[c].add(is_read.astype(jnp.int32)),
-                z.at[c].add(jnp.logical_not(is_read).astype(jnp.int32)),
-            )
-        carry = {
-            **carry,
-            "st": st,
-            "stale": carry["stale"] + jnp.sum(res.stale.astype(jnp.int32)),
-            "viol": carry["viol"]
-            + jnp.sum(res.violation.astype(jnp.int32)),
-            "reads": carry["reads"] + jnp.sum(is_read.astype(jnp.int32)),
-        }
-        if geo_on:
-            creg = client_reg[ops["client"]]
-            hreg = replica_reg[home]
-            zi = jnp.zeros((G,), jnp.int32)
-            zf = jnp.zeros((G,), jnp.float32)
-            reg = carry["reg"]
-            carry = {**carry, "reg": (
-                reg[0] + zi.at[creg].add(res.stale.astype(jnp.int32)),
-                reg[1] + zi.at[creg].add(is_read.astype(jnp.int32)),
-                reg[2] + zf.at[creg].add(rtt[creg, hreg]),
-                reg[3] + zi.at[creg].add(1),
-            )}
+        with jax.named_scope("telemetry"):
+            is_read = ops["kind"] == duot_lib.READ
+            if telemetry:
+                c = ops["client"]
+                z = jnp.zeros((n_clients,), jnp.int32)
+                ys["tel"] = (
+                    z.at[c].add(res.stale.astype(jnp.int32)),
+                    z.at[c].add(res.violation.astype(jnp.int32)),
+                    z.at[c].add(is_read.astype(jnp.int32)),
+                    z.at[c].add(jnp.logical_not(is_read).astype(jnp.int32)),
+                )
+            carry = {
+                **carry,
+                "st": st,
+                "stale": carry["stale"] + jnp.sum(res.stale.astype(jnp.int32)),
+                "viol": carry["viol"]
+                + jnp.sum(res.violation.astype(jnp.int32)),
+                "reads": carry["reads"] + jnp.sum(is_read.astype(jnp.int32)),
+            }
+            if geo_on:
+                creg = client_reg[ops["client"]]
+                hreg = replica_reg[home]
+                zi = jnp.zeros((G,), jnp.int32)
+                zf = jnp.zeros((G,), jnp.float32)
+                reg = carry["reg"]
+                carry = {**carry, "reg": (
+                    reg[0] + zi.at[creg].add(res.stale.astype(jnp.int32)),
+                    reg[1] + zi.at[creg].add(is_read.astype(jnp.int32)),
+                    reg[2] + zf.at[creg].add(rtt[creg, hreg]),
+                    reg[3] + zi.at[creg].add(1),
+                )}
         # -- observability plane ----------------------------------------
         if o_on:
-            # Staleness age = the resource's post-merge write frontier
-            # minus the version actually served — the distribution
-            # whose upper tail the timed levels bound with Δ.  The same
-            # ages masked to audit-flagged reads are the violation
-            # severities.
-            ob = carry["obs"]
-            age = jnp.maximum(
-                st.cluster.global_version[ops["resource"]] - res.version,
-                0,
-            ).astype(jnp.float32)
-            rows = [age, age]
-            row_mask = [is_read, res.violation]
-            if geo_on:
-                rows.append(rtt[creg, hreg])
-                row_mask.append(is_read)
-            part = kernel_ops.histogram(
-                jnp.stack(rows),
-                lo=ob_lo, hi=ob_hi, n_bins=obs.n_bins,
-                mask=jnp.stack(
-                    [m.astype(jnp.int32) for m in row_mask]
-                ),
-                impl=obs.impl,
-            )
-            hist = ob["hist"].at[:n_op_metrics].add(part)
-            if h_on:
-                hist = hist.at[n_op_metrics].add(kernel_ops.histogram(
-                    st.hints.count.astype(jnp.float32),
-                    lo=0.0, hi=obs.depth_hi, n_bins=obs.n_bins,
+            with jax.named_scope("obs"):
+                # Staleness age = the resource's post-merge write frontier
+                # minus the version actually served — the distribution
+                # whose upper tail the timed levels bound with Δ.  The same
+                # ages masked to audit-flagged reads are the violation
+                # severities.
+                ob = carry["obs"]
+                age = jnp.maximum(
+                    st.cluster.global_version[ops["resource"]] - res.version,
+                    0,
+                ).astype(jnp.float32)
+                rows = [age, age]
+                row_mask = [is_read, res.violation]
+                if geo_on:
+                    rows.append(rtt[creg, hreg])
+                    row_mask.append(is_read)
+                part = kernel_ops.histogram(
+                    jnp.stack(rows),
+                    lo=ob_lo, hi=ob_hi, n_bins=obs.n_bins,
+                    mask=jnp.stack(
+                        [m.astype(jnp.int32) for m in row_mask]
+                    ),
                     impl=obs.impl,
-                ))
-            e_stale = jnp.sum(res.stale.astype(jnp.int32))
-            e_viol = jnp.sum(res.violation.astype(jnp.int32))
-            c0 = ob["counters"]
-            n_reads = jnp.sum(is_read.astype(jnp.int32))
-            carry = {**carry, "obs": {"hist": hist, "counters": {
-                "ops": c0["ops"] + jnp.int32(width),
-                "reads": c0["reads"] + n_reads,
-                "writes": c0["writes"] + jnp.int32(width) - n_reads,
-                "stale": c0["stale"] + e_stale,
-                "viol": c0["viol"] + e_viol,
-                "epochs": c0["epochs"] + 1,
-            }}}
-            ys["obs"] = (e_stale, e_viol)
+                )
+                hist = ob["hist"].at[:n_op_metrics].add(part)
+                if h_on:
+                    hist = hist.at[n_op_metrics].add(kernel_ops.histogram(
+                        st.hints.count.astype(jnp.float32),
+                        lo=0.0, hi=obs.depth_hi, n_bins=obs.n_bins,
+                        impl=obs.impl,
+                    ))
+                e_stale = jnp.sum(res.stale.astype(jnp.int32))
+                e_viol = jnp.sum(res.violation.astype(jnp.int32))
+                c0 = ob["counters"]
+                n_reads = jnp.sum(is_read.astype(jnp.int32))
+                carry = {**carry, "obs": {"hist": hist, "counters": {
+                    "ops": c0["ops"] + jnp.int32(width),
+                    "reads": c0["reads"] + n_reads,
+                    "writes": c0["writes"] + jnp.int32(width) - n_reads,
+                    "stale": c0["stale"] + e_stale,
+                    "viol": c0["viol"] + e_viol,
+                    "epochs": c0["epochs"] + 1,
+                }}}
+                ys["obs"] = (e_stale, e_viol)
         return carry, (ys or None)
 
     has_ys = gx_on or telemetry or o_on
@@ -539,6 +557,10 @@ def shard_runner(run, devices: tuple):
     return fn, NamedSharding(mesh, spec)
 
 
+def _nbytes(*trees) -> int:
+    return sum(int(x.nbytes) for x in jax.tree.leaves(trees))
+
+
 class EpochEngine:
     """One workload replay, device-resident end to end.
 
@@ -602,16 +624,13 @@ class EpochEngine:
             crashes, c.faults is not None, False, c.obs,
         )
 
-    def prepare(self, w) -> dict[str, Any]:
-        """Host-side inputs of one replay: streams, masks, schedule."""
+    def _masks(self, store, n_rounds: int, rem: int, sub: int):
+        """(schedule, per-round masks, tail masks, faulty epochs) of the
+        fault schedule and the gossip cadence."""
         c = self.config
-        sub, rem, n_rounds, emulate = self.plan()
-        store, run = self.runner(w)
         n_epochs_total = n_rounds + (1 if rem else 0)
-
-        schedule = masks = tail_masks = None
-        faulty_full = None
         crashes = c.faults is not None and c.faults.has_crashes
+        schedule = faulty_full = None
         if c.faults is not None:
             schedule = self._anchored_schedule(n_rounds, rem, sub)
             schedule, masks, tail_masks = stream_lib.fault_epoch_inputs(
@@ -644,45 +663,72 @@ class EpochEngine:
             masks["pairs"] = np.asarray(g_pairs[:n_rounds])
             tail_masks["gossip"] = np.asarray(g_active[n_epochs_total - 1])
             tail_masks["pairs"] = np.asarray(g_pairs[n_epochs_total - 1])
+        return schedule, masks, tail_masks, faulty_full
+
+    def prepare(self, w) -> dict[str, Any]:
+        """Host-side inputs of one replay: streams, masks, schedule."""
+        c = self.config
+        with trace_lib.span("engine.prepare", ops=c.n_ops):
+            return self._prepare(w)
+
+    def _prepare(self, w) -> dict[str, Any]:
+        c = self.config
+        sub, rem, n_rounds, emulate = self.plan()
+        store, run = self.runner(w)
+
+        schedule = masks = tail_masks = faulty_full = None
+        if c.faults is not None or (c.gossip is not None
+                                    and c.gossip.enabled):
+            with trace_lib.span("engine.prepare.masks"):
+                schedule, masks, tail_masks, faulty_full = self._masks(
+                    store, n_rounds, rem, sub
+                )
 
         streams, batched_shards, tail_shards = [], [], []
         for s in range(c.n_shards):
-            stream = stream_lib.op_stream(
-                w, c.shard_ops, c.shard_clients, c.shard_resources,
-                c.seed + s, store.n_replicas,
-            )
+            with trace_lib.span(
+                "engine.prepare.stream", shard=s, ops=c.shard_ops
+            ):
+                stream = stream_lib.op_stream(
+                    w, c.shard_ops, c.shard_clients, c.shard_resources,
+                    c.seed + s, store.n_replicas,
+                )
             streams.append(stream)
             if c.faults is not None and emulate:
                 # The faulty flavor builds its apply schedule by hand:
                 # synchronous levels defer to the masked merge under
                 # faults, and every level clamps faulty epochs.
-                batched = {
-                    k: stream[k][: n_rounds * sub].reshape(n_rounds, sub)
-                    for k in stream_lib.OP_COLS
-                }
-                batched["step0"] = (
-                    np.arange(n_rounds, dtype=np.int32) * sub
-                )
-                tail = {
-                    k: stream[k][-max(rem, 1):]
-                    for k in stream_lib.OP_COLS
-                }
-                if store.sync_every > 1:
-                    apply_idx = np.asarray(store.schedule_stream(
-                        stream["client"], stream["home"], stream["kind"]
-                    ))
-                else:
-                    apply_idx = np.zeros(c.shard_ops, np.int32)
-                apply_idx = stream_lib.clamp_apply_idx(
-                    apply_idx, faulty_full, sub, c.shard_ops
-                )
+                with trace_lib.span(
+                    "engine.prepare.batch", shard=s, ops=c.shard_ops
+                ):
+                    batched = {
+                        k: stream[k][: n_rounds * sub].reshape(n_rounds, sub)
+                        for k in stream_lib.OP_COLS
+                    }
+                    batched["step0"] = (
+                        np.arange(n_rounds, dtype=np.int32) * sub
+                    )
+                    tail = {
+                        k: stream[k][-max(rem, 1):]
+                        for k in stream_lib.OP_COLS
+                    }
+                with trace_lib.span("engine.prepare.schedule", shard=s):
+                    if store.sync_every > 1:
+                        apply_idx = np.asarray(store.schedule_stream(
+                            stream["client"], stream["home"], stream["kind"]
+                        ))
+                    else:
+                        apply_idx = np.zeros(c.shard_ops, np.int32)
+                    apply_idx = stream_lib.clamp_apply_idx(
+                        apply_idx, faulty_full, sub, c.shard_ops
+                    )
                 batched["apply_idx"] = apply_idx[
                     : n_rounds * sub
                 ].reshape(n_rounds, sub)
                 tail["apply_idx"] = apply_idx[-max(rem, 1):]
             else:
                 batched, tail = stream_lib.batch_inputs(
-                    stream, store, sub, n_rounds, rem, emulate
+                    stream, store, sub, n_rounds, rem, emulate, shard=s
                 )
             if masks is not None:
                 batched = {**batched, **masks}
@@ -710,43 +756,77 @@ class EpochEngine:
         placed (``"shard_map"`` over one device each when the host has
         ``n_shards`` devices and ``use_devices`` is on, else ``"vmap"``
         on one device, or ``"single"``) and on how many devices.
+
+        The launch is asynchronous: ``out`` is ready once the device has
+        run the scan, which the first host read of it waits for.
         """
         c = self.config
-        prep = self.prepare(w)
-        run = prep["run"]
-        stack = lambda dicts: {                               # noqa: E731
-            k: jnp.asarray(np.stack([np.asarray(d[k]) for d in dicts]))
-            for k in dicts[0]
-        }
-        per_round = None
-        if c.n_shards > 1:
-            batched_s = stack(prep["batched"])
-            tail_s = stack(prep["tails"])
-            devices = jax.devices()
-            _JIT_ENTRIES[0] += 1
-            if c.use_devices and len(devices) >= c.n_shards:
-                # One tenant shard per device.  The shards share no
-                # state, so each device runs the unbatched replay on
-                # its own slice: no cross-device traffic in the scan.
-                devs = tuple(devices[: c.n_shards])
-                fn, sharding = shard_runner(run.jitted, devs)
-                put = functools.partial(jax.device_put, device=sharding)
-                out = fn(jax.tree.map(put, batched_s),
-                         jax.tree.map(put, tail_s))
-                layout = {"mode": "shard_map", "devices": len(devs)}
+        replay_id = trace_lib.next_replay_id()
+        with trace_lib.span(
+            "engine.replay", replay=replay_id, level=c.level.name,
+            tenants=c.n_shards, ops=c.n_ops,
+        ) as sp:
+            prep = self.prepare(w)
+            sp.set(rounds=prep["n_rounds"] + (1 if prep["rem"] else 0))
+            run = prep["run"]
+            stack = lambda dicts: {                           # noqa: E731
+                k: jnp.asarray(np.stack([np.asarray(d[k]) for d in dicts]))
+                for k in dicts[0]
+            }
+            per_round = None
+            if c.n_shards > 1:
+                devices = jax.devices()
+                shard_map = c.use_devices and len(devices) >= c.n_shards
+                with trace_lib.span("engine.place") as place:
+                    batched = stack(prep["batched"])
+                    tail = stack(prep["tails"])
+                    if shard_map:
+                        # One tenant shard per device.  The shards share
+                        # no state, so each device runs the unbatched
+                        # replay on its own slice: no cross-device
+                        # traffic in the scan.
+                        devs = tuple(devices[: c.n_shards])
+                        fn, sharding = shard_runner(run.jitted, devs)
+                        put = functools.partial(
+                            jax.device_put, device=sharding
+                        )
+                        batched = jax.tree.map(put, batched)
+                        tail = jax.tree.map(put, tail)
+                    if place:
+                        place.set(bytes=_nbytes(batched, tail))
+                j0 = jit_entries()
+                with trace_lib.span(
+                    "engine.launch", count_compiles=True
+                ) as launch:
+                    _JIT_ENTRIES[0] += 1
+                    if shard_map:
+                        out = fn(batched, tail)
+                        layout = {"mode": "shard_map", "devices": len(devs)}
+                    else:
+                        out = jax.vmap(run.jitted)(batched, tail)
+                        layout = {"mode": "vmap", "devices": 1}
+                    launch.set(jit_entries=jit_entries() - j0)
             else:
-                out = jax.vmap(run.jitted)(batched_s, tail_s)
-                layout = {"mode": "vmap", "devices": 1}
-        else:
-            b = {k: jnp.asarray(v) for k, v in prep["batched"][0].items()}
-            t = {k: jnp.asarray(v) for k, v in prep["tails"][0].items()}
-            out = run(b, t)
-            layout = {"mode": "single", "devices": 1}
+                with trace_lib.span("engine.place") as place:
+                    b = {k: jnp.asarray(v)
+                         for k, v in prep["batched"][0].items()}
+                    t = {k: jnp.asarray(v)
+                         for k, v in prep["tails"][0].items()}
+                    if place:
+                        place.set(bytes=_nbytes(b, t))
+                j0 = jit_entries()
+                with trace_lib.span(
+                    "engine.launch", count_compiles=True
+                ) as launch:
+                    out = run(b, t)
+                    launch.set(jit_entries=jit_entries() - j0)
+                layout = {"mode": "single", "devices": 1}
         if isinstance(out, tuple):
             out, per_round = out
         prep["out"] = out
         prep["per_round"] = per_round
         prep["layout"] = layout
+        prep["replay_id"] = replay_id
         return prep
 
     def run(self, w) -> dict[str, Any]:

@@ -23,6 +23,7 @@ import numpy as np
 from repro.core import cost_model
 from repro.engine.config import EngineConfig
 from repro.gossip import DIGEST_BYTES
+from repro.obs import trace as trace_lib
 from repro.storage.cluster import PAPER_CLUSTER, ClusterConfig
 from repro.storage.ycsb import Workload
 
@@ -40,17 +41,24 @@ def _severity(config: EngineConfig, store, st) -> float:
             shard_st = st._replace(
                 duot=jax.tree.map(lambda x, i=s: jnp.asarray(x[i]), logs)
             )
-            sev.append(float(
-                store.audit(shard_st, delta=store.delta or 0).severity
-            ))
+            with trace_lib.span(
+                "engine.assemble.audit", shard=s, count_compiles=True
+            ):
+                sev.append(float(
+                    store.audit(shard_st, delta=store.delta or 0).severity
+                ))
         return float(np.mean(sev))
-    return float(store.audit(st, delta=store.delta or 0).severity)
+    with trace_lib.span(
+        "engine.assemble.audit", shard=0, count_compiles=True
+    ):
+        return float(store.audit(st, delta=store.delta or 0).severity)
 
 
 def assemble_flat(config: EngineConfig, prep: dict) -> dict[str, float]:
     out = prep["out"]
     st = out["st"]
-    n_reads_f = max(1, int(out["reads"]))
+    with trace_lib.span("engine.assemble.fetch"):
+        n_reads_f = max(1, int(out["reads"]))
     return {
         "staleness_rate": float(out["stale"]) / n_reads_f,
         "violation_rate": float(out["viol"]) / n_reads_f,
@@ -63,7 +71,8 @@ def assemble_flat(config: EngineConfig, prep: dict) -> dict[str, float]:
 def assemble_sharded(config: EngineConfig, prep: dict) -> dict[str, float]:
     out = prep["out"]
     st = out["st"]
-    n_reads_total = int(jnp.sum(out["reads"]))
+    with trace_lib.span("engine.assemble.fetch"):
+        n_reads_total = int(jnp.sum(out["reads"]))
     return {
         "staleness_rate": float(jnp.sum(out["stale"]))
         / max(1, n_reads_total),
@@ -96,7 +105,8 @@ def assemble_geo(
     recovery = config.durability
     g_on = gossip is not None and gossip.enabled
     st = out["st"]
-    n_reads = int(out["reads"])
+    with trace_lib.span("engine.assemble.fetch"):
+        n_reads = int(out["reads"])
     n_reads_f = max(1, n_reads)
     severity = _severity(config, prep["store"], st)
     stale_rate = float(out["stale"]) / n_reads_f
@@ -310,9 +320,10 @@ def assemble_faulty(
         return int(jnp.sum(x)) if sharded else int(x)
 
     st = out["st"]
-    n_stale, n_viol, n_reads = (
-        total(out["stale"]), total(out["viol"]), total(out["reads"])
-    )
+    with trace_lib.span("engine.assemble.fetch"):
+        n_stale, n_viol, n_reads = (
+            total(out["stale"]), total(out["viol"]), total(out["reads"])
+        )
     ae_ev, prop_ev, n_fail = (
         total(out["ae"]), total(out["prop"]), total(out["fail"])
     )
@@ -580,17 +591,23 @@ def assemble(
 ) -> dict[str, Any]:
     """Dispatch the replay output to its config's result shape."""
     config = engine.config if hasattr(engine, "config") else engine
-    if config.faults is not None:
-        result = assemble_faulty(
-            config, prep, w, cfg, pricing, _return_state
-        )
-    elif config.topology is not None:
-        result = assemble_geo(config, prep, w, cfg, pricing)
-    elif config.n_shards > 1:
-        result = assemble_sharded(config, prep)
-    else:
-        result = assemble_flat(config, prep)
-    if config.obs is not None and config.obs.enabled:
-        result["obs"] = _obs_block(config, prep)
-        result["obs"]["cost_attribution"] = _cost_attribution(result)
+    with trace_lib.span(
+        "engine.assemble", replay=prep.get("replay_id"), count_compiles=True,
+    ):
+        if config.faults is not None:
+            result = assemble_faulty(
+                config, prep, w, cfg, pricing, _return_state
+            )
+        elif config.topology is not None:
+            result = assemble_geo(config, prep, w, cfg, pricing)
+        elif config.n_shards > 1:
+            result = assemble_sharded(config, prep)
+        else:
+            result = assemble_flat(config, prep)
+        if config.obs is not None and config.obs.enabled:
+            with trace_lib.span("engine.assemble.obs"):
+                result["obs"] = _obs_block(config, prep)
+                result["obs"]["cost_attribution"] = _cost_attribution(
+                    result
+                )
     return result

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.core.consistency import ConsistencyLevel
 from repro.core.replicated_store import ReplicatedStore, merge_cadence
+from repro.obs import trace as trace_lib
 
 if TYPE_CHECKING:
     # Annotation-only: the runtime ycsb import is deferred into
@@ -103,24 +104,30 @@ def cadence_plan(
 def batch_inputs(
     stream: dict[str, np.ndarray], store: ReplicatedStore,
     sub: int, n_rounds: int, rem: int, emulate: bool,
+    shard: int = 0,
 ) -> tuple[dict[str, Any], dict[str, Any]]:
     """(batched, tail) scan inputs for one stream under one plan.
 
     Rounds carry their first op's global index (``step0``); the
     emulated-cadence levels also carry the precomputed apply-point
     schedule, sliced per round.  ``rem == 0`` still builds a one-op
-    dummy tail (the jitted runner ignores it).
+    dummy tail (the jitted runner ignores it).  ``shard`` names the
+    tenant in the spans.
     """
-    batched = {
-        k: jnp.asarray(stream[k][: n_rounds * sub].reshape(n_rounds, sub))
-        for k in OP_COLS
-    }
-    batched["step0"] = jnp.arange(n_rounds, dtype=jnp.int32) * sub
-    tail = {k: jnp.asarray(stream[k][-max(rem, 1):]) for k in OP_COLS}
+    with trace_lib.span(
+        "engine.prepare.batch", shard=shard, ops=n_rounds * sub + rem
+    ):
+        batched = {
+            k: jnp.asarray(stream[k][: n_rounds * sub].reshape(n_rounds, sub))
+            for k in OP_COLS
+        }
+        batched["step0"] = jnp.arange(n_rounds, dtype=jnp.int32) * sub
+        tail = {k: jnp.asarray(stream[k][-max(rem, 1):]) for k in OP_COLS}
     if emulate and store.sync_every > 1:
-        apply_idx = store.schedule_stream(
-            stream["client"], stream["home"], stream["kind"]
-        )
+        with trace_lib.span("engine.prepare.schedule", shard=shard):
+            apply_idx = store.schedule_stream(
+                stream["client"], stream["home"], stream["kind"]
+            )
         batched["apply_idx"] = apply_idx[: n_rounds * sub].reshape(
             n_rounds, sub
         )

@@ -128,6 +128,7 @@ def digest_compare_pallas(
             # Row tiles are independent; let the compiler parallelize.
             dimension_semantics=("parallel",),
         ),
+        name="digest_compare",
         interpret=interpret,
     )(packed)
 

@@ -152,5 +152,6 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

@@ -142,6 +142,7 @@ def histogram_pallas(
             # run in order.
             dimension_semantics=("arbitrary",),
         ),
+        name="histogram",
         interpret=interpret,
     )(vals, mask, params)
 

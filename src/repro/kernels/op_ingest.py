@@ -365,6 +365,7 @@ def op_ingest_pallas(
             # rows published by earlier diagonal steps: strict order.
             dimension_semantics=("arbitrary",),
         ),
+        name="op_ingest",
         interpret=interpret,
     )(jnp.asarray(ts), jnp.asarray(us), meta, meta_t, pend)
     return out[:b, OCC], out[:b, RAW], out[:b, FLOOR]

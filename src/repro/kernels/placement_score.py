@@ -117,6 +117,7 @@ def placement_score(
             # Tiles are independent; let the compiler parallelize.
             dimension_semantics=("parallel",),
         ),
+        name="placement_score",
         interpret=interpret,
     )(
         jnp.asarray(reads, jnp.float32),

@@ -131,6 +131,7 @@ def policy_score(
             # Tiles are independent; let the compiler parallelize.
             dimension_semantics=("parallel",),
         ),
+        name="policy_score",
         interpret=interpret,
     )(
         jnp.asarray(sess, jnp.float32),

@@ -146,5 +146,6 @@ def session_floor(
             # The floor accumulator carries across grid steps.
             dimension_semantics=("arbitrary",),
         ),
+        name="session_floor",
         interpret=interpret,
     )(replica_version, read_floor, write_floor, ops_meta)

@@ -137,5 +137,6 @@ def vclock_audit(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
+        name="vclock_audit",
         interpret=interpret,
     )(vc, vc.T, meta, meta.T)

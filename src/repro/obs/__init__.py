@@ -2,8 +2,9 @@
 
   * :mod:`repro.obs.metrics` — the typed metric registry whose
     histogram/counter state lives in the unified engine's scan carry;
-  * :mod:`repro.obs.trace`   — host-side span tracing of the engine
-    lifecycle (Chrome trace-event JSON + JSONL);
+  * :mod:`repro.obs.trace`   — the engine's host spans on the
+    profiler's clock (profiler TraceMe events, and a Chrome trace-event
+    export from a recording ``Tracer``);
   * :mod:`repro.obs.report`  — per-run report rendering and the
     ``python -m repro.obs.report`` CLI.
 

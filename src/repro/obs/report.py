@@ -182,14 +182,15 @@ def selftest(tmpdir=None, n_ops: int = 512) -> str:
     result, tracer = trace_lib.traced_run(config, WORKLOAD_A)
     trace_path = tmpdir / "trace.json"
     tracer.write_chrome(trace_path)
-    tracer.write_jsonl(tmpdir / "trace.jsonl")
     events = trace_lib.load_chrome(trace_path)
     names = {e["name"] for e in events}
-    for required in ("config", "stages", "execute", "jit_entries"):
+    for required in ("config", "stages", "engine.replay", "engine.launch",
+                     "engine.assemble"):
         if required not in names:
             raise AssertionError(f"trace missing {required!r} event")
     (entries,) = [
-        e["args"]["count"] for e in events if e["name"] == "jit_entries"
+        e["args"]["jit_entries"] for e in events
+        if e["name"] == "engine.launch"
     ]
     if entries != 1:
         raise AssertionError(f"replay took {entries} jit entries, not 1")

@@ -12,7 +12,7 @@ Four contracts:
   the pinned pre-obs golden), and an obs-on replay still takes exactly
   one jit entry;
 * the span tracer's Chrome export round-trips through JSON against the
-  event schema.
+  event schema, and a traced replay splits compile from execute.
 """
 
 from __future__ import annotations
@@ -222,21 +222,20 @@ def test_obs_summary_shape():
 
 def test_trace_chrome_round_trip(tmp_path):
     tr = trace_lib.Tracer(run_id="t")
-    with tr.span("outer", k=1):
-        tr.instant("mark", note="x")
+    with tr.recording():
+        with trace_lib.span("outer", k=1):
+            tr.instant("mark", note="x")
     path = tmp_path / "trace.json"
     tr.write_chrome(path)
-    tr.write_jsonl(tmp_path / "trace.jsonl")
     events = trace_lib.load_chrome(path)
     assert [e["name"] for e in events] == ["mark", "outer"]
     for ev in events:
         assert set(trace_lib.EVENT_KEYS) <= set(ev)
     outer = events[-1]
     assert outer["ph"] == "X" and outer["dur"] >= 0
+    assert outer["args"] == {"k": 1}
     obj = json.loads(path.read_text())
     assert obj["otherData"]["schema"] == trace_lib.TRACE_SCHEMA
-    jsonl = (tmp_path / "trace.jsonl").read_text().splitlines()
-    assert [json.loads(l)["name"] for l in jsonl] == ["mark", "outer"]
 
 
 def test_trace_validation_rejects_malformed_events():
@@ -256,20 +255,30 @@ def test_trace_validation_rejects_malformed_events():
 
 @pytest.mark.slow
 def test_traced_run_splits_compile_from_execute():
+    """The compile of the replay scan lands in its own event inside the
+    launch span, which counts it; prepare, launch and assembly are
+    spans of their own, and the replay takes one jit entry."""
+    # A batch no other test compiles: the launch compiles the scan.
     config = EngineConfig(
-        golden_bridge.ConsistencyLevel.X_STCC, n_ops=512, batch_size=128,
+        golden_bridge.ConsistencyLevel.X_STCC, n_ops=640, batch_size=160,
         obs=ObsConfig(),
     )
     result, tr = trace_lib.traced_run(config, WORKLOAD_A)
     assert "obs" in result
     names = [e["name"] for e in tr.events]
-    for required in ("config", "stages", "prepare", "compile",
-                     "execute", "assemble", "jit_entries"):
+    for required in ("config", "stages", "engine.replay", "engine.prepare",
+                     "engine.launch", "engine.assemble",
+                     "engine.assemble.obs"):
         assert required in names, required
-    (entries,) = [
-        e["args"]["count"] for e in tr.events if e["name"] == "jit_entries"
-    ]
-    assert entries == 1
+    (launch,) = tr.spans("engine.launch")
+    assert launch["args"]["jit_entries"] == 1
+    compiles = [e for e in tr.spans("compile")
+                if launch["ts"] <= e["ts"]
+                and e["ts"] + e["dur"] <= launch["ts"] + launch["dur"]]
+    assert [e["args"]["fun_name"] for e in compiles] == ["jit(run)"]
+    assert launch["args"]["compiles"] + launch["args"]["cache_loads"] == 1
+    (execute,) = [e for e in tr.spans("engine.assemble.fetch")]
+    assert execute["ts"] >= launch["ts"] + launch["dur"]
     (stages,) = [
         e["args"] for e in tr.events if e["name"] == "stages"
     ]
