@@ -1051,11 +1051,11 @@ class ReplicatedStore:
         self, state: StoreState, client: Array | int, resource: Array | int
     ) -> Array:
         """The MR/RYW floor: min version admissible for this session."""
-        c = jnp.asarray(client, jnp.int32)
-        r = jnp.asarray(resource, jnp.int32)
-        return jnp.maximum(
-            state.cluster.read_floor[c, r], state.cluster.write_floor[c, r]
+        cl = state.cluster
+        cr = xstcc.floor_index(
+            cl, jnp.asarray(client, jnp.int32), jnp.asarray(resource, jnp.int32)
         )
+        return jnp.maximum(cl.read_floor[cr], cl.write_floor[cr])
 
     def admit_batch(
         self,
@@ -1081,21 +1081,24 @@ class ReplicatedStore:
         p = jnp.asarray(replica, jnp.int32)
         r = jnp.asarray(resource, jnp.int32)
         cl = state.cluster
+        # The admission kernels take the floors as (C, R) tables.
+        C = cl.session_vc.shape[0]
+        floors = (cl.read_floor.reshape(C, -1), cl.write_floor.reshape(C, -1))
         if use_kernel:
             from repro.kernels import ops as kernel_ops
 
             served, adm, _, new_rf = kernel_ops.session_admit(
-                cl.replica_version, cl.read_floor, cl.write_floor,
+                cl.replica_version, *floors,
                 c, p, r, enforce=self.enforce_sessions,
             )
         else:
             from repro.kernels import ref as kernel_ref
 
             served, adm, _, new_rf = kernel_ref.session_admit_ref(
-                cl.replica_version, cl.read_floor, cl.write_floor,
+                cl.replica_version, *floors,
                 c, p, r, enforce=self.enforce_sessions,
             )
-        cluster = cl._replace(read_floor=new_rf)
+        cluster = cl._replace(read_floor=new_rf.reshape(-1))
         return state._replace(cluster=cluster), served, adm
 
     # -- audit / GC ---------------------------------------------------------------

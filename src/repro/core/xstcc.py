@@ -53,8 +53,11 @@ class ClusterState(NamedTuple):
     replica_version: Array   # (P, R) int32 — applied version per resource
     replica_vc: Array        # (P, C) int32 — applied vector clock
     session_vc: Array        # (C, C) int32 — each session's clock
-    read_floor: Array        # (C, R) int32 — MR floor
-    write_floor: Array       # (C, R) int32 — RYW floor
+    # The session floors are flat row-major: cell (c, r) sits at
+    # ``c * R + r`` (:func:`floor_index`), so a batch's scatter into them
+    # acts in place, with no (C, R) layout for the compiler to convert.
+    read_floor: Array        # (C*R,) int32 — MR floor
+    write_floor: Array       # (C*R,) int32 — RYW floor
     global_version: Array    # (R,) int32 — latest committed version
     # Pending writes ring (bounded): writes committed but not yet applied
     # everywhere. Slots cycle; capacity bounds in-flight writes.
@@ -74,12 +77,13 @@ def make_cluster(
     n_replicas: int, n_clients: int, n_resources: int, pending_cap: int = 128
 ) -> ClusterState:
     P, C, R, Q = n_replicas, n_clients, n_resources, pending_cap
+    assert C * R < 2**31, f"{C} x {R} floor cells overflow an int32 index"
     return ClusterState(
         replica_version=jnp.zeros((P, R), jnp.int32),
         replica_vc=jnp.zeros((P, C), jnp.int32),
         session_vc=jnp.zeros((C, C), jnp.int32),
-        read_floor=jnp.zeros((C, R), jnp.int32),
-        write_floor=jnp.zeros((C, R), jnp.int32),
+        read_floor=jnp.zeros((C * R,), jnp.int32),
+        write_floor=jnp.zeros((C * R,), jnp.int32),
         global_version=jnp.zeros((R,), jnp.int32),
         pend_client=jnp.full((Q,), -1, jnp.int32),
         pend_resource=jnp.full((Q,), -1, jnp.int32),
@@ -92,6 +96,11 @@ def make_cluster(
         pend_dropped=jnp.zeros((), jnp.int32),
         clock=jnp.zeros((), jnp.int32),
     )
+
+
+def floor_index(state: ClusterState, client: Array, resource: Array) -> Array:
+    """Index of the ``(client, resource)`` cell in the flat floors."""
+    return client * state.global_version.shape[0] + resource
 
 
 def _saturating_add(counter: Array, n: Array) -> Array:
@@ -122,6 +131,7 @@ def client_write(
     c = jnp.asarray(client, jnp.int32)
     p = jnp.asarray(replica, jnp.int32)
     r = jnp.asarray(resource, jnp.int32)
+    cr = floor_index(state, c, r)
 
     svc = vclock.receive(state.session_vc[c], state.replica_vc[p], c)
     ver = state.global_version[r] + 1
@@ -148,8 +158,8 @@ def client_write(
         replica_version=replica_version,
         replica_vc=replica_vc,
         session_vc=state.session_vc.at[c].set(svc),
-        write_floor=state.write_floor.at[c, r].max(ver),
-        read_floor=state.read_floor.at[c, r].max(ver),
+        write_floor=state.write_floor.at[cr].max(ver),
+        read_floor=state.read_floor.at[cr].max(ver),
         global_version=state.global_version.at[r].set(ver),
         pend_client=state.pend_client.at[q].set(c, mode="drop"),
         pend_resource=state.pend_resource.at[q].set(r, mode="drop"),
@@ -195,9 +205,10 @@ def client_read(
     c = jnp.asarray(client, jnp.int32)
     p = jnp.asarray(replica, jnp.int32)
     r = jnp.asarray(resource, jnp.int32)
+    cr = floor_index(state, c, r)
 
     raw = state.replica_version[p, r]
-    floor = jnp.maximum(state.read_floor[c, r], state.write_floor[c, r])
+    floor = jnp.maximum(state.read_floor[cr], state.write_floor[cr])
     admissible = raw >= floor
     enforce = jnp.asarray(enforce_sessions, bool)
     served = jnp.where(enforce, jnp.maximum(raw, floor), raw)
@@ -208,7 +219,7 @@ def client_read(
     svc = vclock.receive(state.session_vc[c], state.replica_vc[p], c)
     new = state._replace(
         session_vc=state.session_vc.at[c].set(svc),
-        read_floor=state.read_floor.at[c, r].max(served),
+        read_floor=state.read_floor.at[cr].max(served),
         clock=state.clock + 1,
     )
     return ReadResult(
@@ -318,13 +329,14 @@ def apply_op_batch(
         )
     # The stages below carry ``jax.named_scope`` names, which reach the
     # device trace as the ops' name stacks: ``floors`` (the reads and
-    # writes of the (C, R) floors and the (P, R) / (R,) versions),
+    # writes of the flat (C*R,) floors and the (P, R) / (R,) versions),
     # ``op_ingest`` (the prefix kernel), ``vclock_scan`` (the clock
     # chain) and ``pending_ring`` (slot assignment).
     with jax.named_scope("floors"):
+        cr = floor_index(state, c, r)
         g0 = state.global_version[r]
         raw0 = state.replica_version[p, r]
-        floor0 = jnp.maximum(state.read_floor[c, r], state.write_floor[c, r])
+        floor0 = jnp.maximum(state.read_floor[cr], state.write_floor[cr])
     with jax.named_scope("op_ingest"):
         occ, raw, floor = kernel_ops.op_ingest(
             c, p, r, is_w, g0, raw0, floor0,
@@ -412,10 +424,10 @@ def apply_op_batch(
     with jax.named_scope("floors"):
         floors = dict(
             replica_version=state.replica_version.at[p, r].max(verw_masked),
-            read_floor=state.read_floor.at[c, r].max(
+            read_floor=state.read_floor.at[cr].max(
                 jnp.where(is_w, ver_w, served)
             ),
-            write_floor=state.write_floor.at[c, r].max(verw_masked),
+            write_floor=state.write_floor.at[cr].max(verw_masked),
             global_version=state.global_version.at[r].max(verw_masked),
         )
 

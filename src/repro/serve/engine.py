@@ -892,11 +892,8 @@ class ShardedServingRouter:
         guarded = self.level.is_session_guarded
         if guarded:
             def admit(st, s, pref):
-                cl = st.cluster
-                floor = jnp.maximum(
-                    cl.read_floor[s, 0], cl.write_floor[s, 0]
-                )
-                return cl.replica_version[pref, 0] >= floor, floor
+                floor = self._sharded.store.session_floor(st, s, 0)
+                return st.cluster.replica_version[pref, 0] >= floor, floor
 
             adm, floor = jax.vmap(admit)(self._st, sid, preferred)
             ok = adm & alive

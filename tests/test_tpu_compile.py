@@ -12,6 +12,7 @@ only the worker given this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +112,40 @@ def test_digest_compare_compiles(one_chip, pairs, ranges):
 def on_tpu(monkeypatch):
     """Make the kernel wrappers see a TPU backend."""
     monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+
+
+def test_floor_scatters_need_no_relayout(one_chip, on_tpu):
+    """Two rounds of ``apply_op_batch`` at the engine's shapes (16
+    sessions, 5M rows, B=4096, Pallas ingest) as a ``lax.scan``: the
+    flat floors are scattered in place, so no loop or slice update of
+    the compiled program moves a (16, 5M) floor between layouts."""
+    from repro.core import xstcc
+
+    c, r, b = 16, 5_000_000, 4096
+    state = jax.eval_shape(lambda: xstcc.make_cluster(3, c, r, 2 * b))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        state,
+    )
+
+    def rounds(state, ops_):
+        def step(st, op):
+            res = xstcc.apply_op_batch(
+                st, client=op[0], replica=op[1], resource=op[2],
+                kind=op[3], ingest="pallas",
+            )
+            return res.state, res.version
+        return jax.lax.scan(step, state, ops_)
+
+    ops_ = jax.ShapeDtypeStruct((2, 4, b), I32, sharding=one_chip)
+    text = jax.jit(rounds).lower(state, ops_).compile().as_text()
+    assert "tpu_custom_call" in text
+    relayouts = [
+        line for line in text.splitlines()
+        if re.search(r"\b(while|dynamic-update-slice)\(", line)
+        and re.search(rf"s32\[(1,)?{c},{r}\]", line)
+    ]
+    assert relayouts == []
 
 
 def test_kernels_never_interpreted_on_tpu(on_tpu):
