@@ -66,9 +66,50 @@ PHASE_NAMES = {
 }
 
 # ODG edge-kind weights for severity (paper §3.4.1: Timed, Causal, Data).
-WEIGHT_TIMED = 1.0
-WEIGHT_CAUSAL = 2.0
-WEIGHT_DATA = 3.0
+WEIGHT_TIMED = 1
+WEIGHT_CAUSAL = 2
+WEIGHT_DATA = 3
+
+
+@jax.jit
+def ratio_f32(num: Array, den: Array) -> Array:
+    """``num / den`` rounded to the nearest float32, ties to even, for
+    int32 ``0 <= num < 2**30`` and ``1 <= den < 2**30``.
+
+    A TPU divides float32 by a refined reciprocal, which can land one
+    unit in the last place off the IEEE quotient; this long division in
+    int32 gives the IEEE quotient on every backend.  Jitted: the audit
+    assembles its result eagerly, and the long division's ~120 small
+    ops would each be a dispatch."""
+    num = jnp.asarray(num, jnp.int32)
+    den = jnp.asarray(den, jnp.int32)
+    safe = jnp.maximum(num, 1)
+    # 2**e <= num/den < 2**(e+1); scale both to the same bit length.
+    e = jax.lax.clz(den) - jax.lax.clz(safe)
+    a = jnp.left_shift(safe, jnp.maximum(-e, 0))
+    b = jnp.left_shift(den, jnp.maximum(e, 0))
+    low = a < b
+    e = e - low
+    a = jnp.where(low, a << 1, a)
+    # a / b in [1, 2): 24 significant bits, then a guard bit and the
+    # sticky remainder.
+    r = a - b
+    m = jnp.int32(1)
+    for _ in range(23):
+        r = r << 1
+        bit = r >= b
+        r = jnp.where(bit, r - b, r)
+        m = (m << 1) | bit.astype(jnp.int32)
+    r = r << 1
+    guard = r >= b
+    sticky = jnp.where(guard, r - b, r) != 0
+    m = m + (guard & (sticky | ((m & 1) == 1))).astype(jnp.int32)
+    carry = m == (1 << 24)
+    m = jnp.where(carry, 1 << 23, m)
+    e = e + carry.astype(jnp.int32)
+    bits = ((e + 127) << 23) | (m - (1 << 23))
+    q = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    return jnp.where(num == 0, jnp.float32(0), q)
 
 
 class AuditResult(NamedTuple):
@@ -202,7 +243,7 @@ def _assemble_result(
         + WEIGHT_CAUSAL * (causal_edge & ~data_edge)
         + WEIGHT_TIMED * (base & ~causal_edge & ~data_edge)
     )
-    severity = jnp.sum(w) / jnp.maximum(jnp.sum(denom), 1.0)
+    severity = ratio_f32(jnp.sum(w), jnp.maximum(jnp.sum(denom), 1))
 
     return AuditResult(
         phase=phase,

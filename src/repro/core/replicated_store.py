@@ -34,12 +34,15 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import audit as audit_lib
 from repro.core import duot as duot_lib
 from repro.core import xstcc
 from repro.core.consistency import ConsistencyLevel
+from repro.kernels import cadence_schedule
 from repro.kernels import ops as kernel_ops
+from repro.kernels import ref as kernel_ref
 
 Array = jax.Array
 
@@ -67,52 +70,174 @@ def merge_cadence(
     return merge_every, max(1, delta // 3)
 
 
-_BIG = 2 ** 30  # "never" sentinel for the cadence emulator
+# The apply point of a read, or of a write no merge of its stream applies.
+NEVER = kernel_ref.NEVER
 
 
-def _timed_index(op_step: Array, s: int, d: int) -> Array:
-    """Op index at which a write issued at ``op_step`` is Δ-overdue.
+def pending_periods(sync_every: int, delta: int) -> int:
+    """Merge periods a write can wait in the sequential merge's pending
+    set before it is Δ-overdue.
 
-    Replays the sequential schedule in op-index space: merges run after
-    ops ``k*s - 1``, the logical clock at op ``g`` is ``g + g//s`` (one
-    tick per op, one per merge), and the timed bound unconditionally
-    applies a write at the first merge whose clock exceeds the write's
-    commit clock by Δ."""
-    cs = op_step + op_step // s
-    k_timed = (d + cs + 1 + s) // (s + 1)     # ceil((d+cs+1)/(s+1))
-    k_after = (op_step + s) // s              # ceil((g+1)/s)
-    return jnp.maximum(k_timed, k_after) * s
+    The clock counts one per op and one per merge, so a write at
+    position ``j`` of its period has waited ``a*(s+1) + s - j`` ticks at
+    the ``a``-th merge after its own period's: the last position
+    (``j = s-1``) is overdue once ``a*(s+1) + 1 >= Δ``."""
+    return 1 + max(0, -(-(delta - 1) // (sync_every + 1)))
+
+
+class Schedule(NamedTuple):
+    """The scheduler's output: apply points and its four counters."""
+
+    apply: Array          # (n,) int32 — apply op-index, NEVER for reads
+    dep_applied: Array    # () int32 — writes applied by the dependency test
+    delta_applied: Array  # () int32 — writes applied as Δ-overdue
+    pending_peak: Array   # () int32 — most writes pending at one merge
+    overflow: Array       # () int32 — writes evicted from a full window
+
+    def counts(self) -> dict[str, Array]:
+        """The four counters by name."""
+        return {k: getattr(self, k) for k in self._fields[1:]}
 
 
 @functools.lru_cache(maxsize=None)
 def _stream_scheduler(sync_every: int, delta: int, n_clients: int,
-                      n_replicas: int):
-    """Jitted apply-point scheduler for one cadence configuration."""
+                      n_replicas: int, periods: int | None = None,
+                      impl: str = "scan"):
+    """Jitted apply-point scheduler for one cadence configuration.
 
-    @jax.jit
-    def sched(client: Array, replica: Array, kind: Array) -> Array:
+    Carries the sequential protocol's clocks and pending set, one step
+    per merge period: ``impl="scan"`` as a ``lax.scan`` (the oracle, and
+    the path off the TPU), ``"pallas"`` as one kernel
+    (``repro.kernels.cadence_schedule``).  The pending set is a window
+    of the last ``periods`` periods' ops (``pending_periods`` unless
+    given), each at a fixed slot, so the write's age and position make
+    its Δ test a constant.  A write still pending when it leaves the
+    window is counted in ``overflow``; with the derived ``periods`` none
+    can be.
+    """
+    s, d, C, P = sync_every, delta, n_clients, n_replicas
+    A = pending_periods(s, d) if periods is None else periods
+    K = A * s
+    age = np.arange(A)[:, None]
+    pos = np.arange(s)[None, :]
+    overdue = jnp.asarray((age * (s + 1) + s - pos >= d).reshape(K))
+    earlier = jnp.asarray(np.tril(np.ones((s, s), bool), -1))  # [i, j]: j<i
+    eye = jnp.asarray(np.eye(s, dtype=bool))
+    sessions = jnp.arange(C, dtype=jnp.int32)
+    replicas = jnp.arange(P, dtype=jnp.int32)
+
+    def joined(mask: Array, clocks: Array) -> Array:
+        """Row i: the join of the clocks j with ``mask[i, j]``."""
+        return jnp.max(jnp.where(mask[:, :, None], clocks[None], 0), axis=1)
+
+    def period(carry, x):
+        S, R, vc, live, cl, counts = carry
+        c, p, w, real = x
+        # The period's ops in closed form.  An op's clock is the join of
+        # the clocks its causal predecessors in the period read (their
+        # session's and their replica's at the period's start), with
+        # each session's own component at its ops' count: a component
+        # c of any clock is at most session c's own count.
+        same = c[:, None] == c[None, :]
+        reach = earlier & (same | (w[None, :] & (p[None, :] == p[:, None])))
+        reach = reach | eye
+        for _ in range(max(0, (s - 1).bit_length())):
+            reach = jnp.any(reach[:, :, None] & reach[None, :, :], axis=1)
+        own = c[:, None] == sessions[None, :]
+        tick = S[c, c] + jnp.sum(same & (earlier | eye), axis=1)
+        read = jnp.where(own, tick[:, None], jnp.maximum(S[c], R[p]))
+        svc = joined(reach, read)
+        S = jnp.maximum(S, joined(own.T, svc))
+        R = jnp.maximum(R, joined(w[None, :] & (p[None, :] == replicas[:, None]),
+                                  svc))
+        # The pending window, this period's ops at age 0.
+        vc = jnp.concatenate([svc, vc[:-s]])
+        live = jnp.concatenate([w, live[:-s]])
+        cl = jnp.concatenate([c, cl[:-s]])
+        # The merge: one pass in (clock sum, session) order.  Every
+        # applied write joins every replica clock alike, so "dependency
+        # at or below every replica clock" is "at or below the join of
+        # the replica clocks' minimum and the clocks applied earlier in
+        # the pass".  The pass is the least solution of that triangular
+        # system, reached by iterating from the overdue writes.
+        key = jnp.sum(vc, axis=1)
+        first = (key[:, None] < key[None, :]) | (
+            (key[:, None] == key[None, :]) & (cl[:, None] < cl[None, :]))
+        dep = vc - (cl[:, None] == sessions[None, :]).astype(jnp.int32)
+        floor = jnp.min(R, axis=0)
+        forced = live & overdue
+
+        def ready(applied):
+            learned = joined(first.T & applied[None, :], vc)
+            return forced | (live & jnp.all(
+                dep <= jnp.maximum(floor, learned), axis=1))
+
+        def again(state):
+            return state[0]
+
+        def step(state):
+            applied = ready(state[1])
+            return jnp.any(applied != state[1]), applied
+
+        _, applied = jax.lax.while_loop(again, step, (jnp.bool_(True), forced))
+        R = jnp.maximum(R, jnp.max(jnp.where(applied[:, None], vc, 0), axis=0))
+        live = live & ~applied
+        one = real.astype(jnp.int32)
+        counts = (
+            counts[0] + one * jnp.sum(applied & ~overdue),
+            counts[1] + one * jnp.sum(applied & overdue),
+            jnp.maximum(counts[2], one * jnp.sum(live | applied)),
+            counts[3] + one * jnp.sum(live[-s:]),
+        )
+        return (S, R, vc, live, cl, counts), applied
+
+    def scan(client: Array, replica: Array, kind: Array, n: int):
+        n_per = -(-n // s)
+        pad = n_per * s - n
+
+        def periods_of(x, fill):
+            return jnp.pad(x, (0, pad), constant_values=fill).reshape(n_per, s)
+
+        xs = (
+            periods_of(client, 0), periods_of(replica, 0),
+            periods_of(kind == xstcc.WRITE, False),
+            (jnp.arange(1, n_per + 1) * s) <= n,
+        )
+        z = jnp.int32(0)
+        carry = (
+            jnp.zeros((C, C), jnp.int32), jnp.zeros((P, C), jnp.int32),
+            jnp.zeros((K, C), jnp.int32), jnp.zeros((K,), bool),
+            jnp.zeros((K,), jnp.int32), (z, z, z, z),
+        )
+        carry, applied = jax.lax.scan(period, carry, xs)
+        return applied, carry[-1]
+
+    def schedule(client: Array, replica: Array, kind: Array) -> Schedule:
         n = client.shape[0]
-        g = jnp.arange(n, dtype=jnp.int32)
-        base = (g // sync_every + 1) * sync_every
-        timed = _timed_index(g, sync_every, delta)
-        is_w = kind == xstcc.WRITE
-
-        def step(carry, op):
-            last_a, rep_a = carry
-            ci, pi, wi, ti, bi = op
-            a_w = jnp.minimum(
-                ti, jnp.maximum(bi, jnp.maximum(last_a[ci], rep_a[pi]))
+        if impl == "pallas":
+            point, counts = cadence_schedule.schedule_pallas(
+                cadence_schedule.pack_ops(client, replica,
+                                          kind == xstcc.WRITE),
+                s=s, d=d, n_clients=C, n_replicas=P, periods=A,
             )
-            last_a = last_a.at[ci].set(jnp.where(wi, a_w, jnp.int32(_BIG)))
-            rep_a = jnp.where(wi, rep_a.at[pi].max(a_w), rep_a)
-            return (last_a, rep_a), jnp.where(wi, a_w, jnp.int32(_BIG))
+            return Schedule(point, *counts)
+        applied, counts = scan(client, replica, kind, n)
+        # applied[k, a*s + j]: the merge after period k applied op j of
+        # period k - a; that op's apply point is (k + 1) * s.
+        n_per = applied.shape[0]
+        hist = applied.reshape(n_per, A, s)
+        q = jnp.arange(n_per, dtype=jnp.int32)[:, None]
+        point = jnp.full((n_per, s), NEVER, jnp.int32)
+        for a in range(A):
+            hit = jnp.concatenate([hist[a:, a],
+                                   jnp.zeros((min(a, n_per), s), bool)])
+            point = jnp.where(hit, (q + a + 1) * s, point)
+        point = point.reshape(-1)[:n]
+        # A partial last period has no merge.
+        point = jnp.where(point > n, NEVER, point)
+        return Schedule(point, *counts)
 
-        carry = (jnp.zeros((n_clients,), jnp.int32),
-                 jnp.zeros((n_replicas,), jnp.int32))
-        _, a = jax.lax.scan(step, carry, (client, replica, is_w, timed, base))
-        return a
-
-    return sched
+    return jax.jit(schedule)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -289,31 +414,42 @@ class ReplicatedStore:
     # -- merge-cadence emulation -------------------------------------------------
 
     def schedule_stream(
-        self, client: Array, replica: Array, kind: Array
-    ) -> Array:
-        """Emulated sequential apply op-index for each write of a stream.
+        self, client: Array, replica: Array, kind: Array, *,
+        counters: bool = False,
+    ) -> Array | Schedule:
+        """Sequential apply op-index of each write of a stream.
 
-        The sequential merge applies a write at the first merge point
-        where its causal dependencies are applied everywhere, and at its
-        Δ-overdue point unconditionally.  In op-index space that is
+        Replays the level's sequential protocol on the stream's clocks:
+        each op takes ``max(session clock, replica clock)`` ticked at its
+        session, a write joins its clock into its coordinator's and
+        waits in the pending set, and after every ``sync_every``-th op a
+        merge walks the pending writes once, in ``(clock sum, session)``
+        order, applying a write when it is Δ-overdue (the clock counts
+        one per op and one per merge) or when its clock less its own
+        tick is at or below every replica clock; an applied write joins
+        every replica clock before the next is tested.  A write applied
+        at the merge after op ``i`` has apply point ``i + 1``; reads,
+        and writes no merge applies, get ``NEVER``.  The schedule
+        depends only on the op sequence and the cadence, so callers
+        precompute it for a whole run and slice it per batch.
 
-          ``A(w) = min(timed(w), max(boundary_after(w), A(prev same-client
-          write), max A over earlier same-coordinator writes))``
-
-        with the causal fast path broken (pure timed) when the session's
-        previous op was a *read*: the read ticks a clock component no
-        replica ever learns, so the write's dependency vector can only be
-        satisfied by its own application.  Reads get a "never" sentinel.
-        The schedule depends only on the op sequence and the cadence, so
-        callers precompute it for a whole run and slice it per batch.
+        With ``counters`` the :class:`Schedule` is returned: the apply
+        points and, as device scalars, the writes applied by the
+        dependency test and by the Δ bound, the pending set's peak, and
+        the writes that overflowed its window (0 by construction).
         """
         sched = _stream_scheduler(
-            self.sync_every, self.delta, self.n_clients, self.n_replicas
+            self.sync_every, self.delta, self.n_clients, self.n_replicas,
+            impl=cadence_schedule.resolve_impl(
+                self.sync_every, self.n_clients, self.n_replicas,
+                pending_periods(self.sync_every, self.delta),
+            ),
         )
-        return sched(
+        out = sched(
             jnp.asarray(client, jnp.int32), jnp.asarray(replica, jnp.int32),
             jnp.asarray(kind, jnp.int32),
         )
+        return out if counters else out.apply
 
     # -- batch ops --------------------------------------------------------------
 
@@ -348,11 +484,17 @@ class ReplicatedStore:
           * synchronous levels (``sync_every == 1``): ``apply_index = 0``
             — every write is visible to every later op at any replica,
             exactly what a merge-after-every-op (Δ=0) schedule serves;
-          * causal-family levels: each write carries an emulated
-            sequential apply point in ``apply_index`` (the batch's slice
-            of :meth:`schedule_stream`) and becomes visible at remote
-            replicas from that op index on — both for writes inside the
-            batch and for writes still pending from earlier batches.
+          * causal-family levels: each write carries in ``apply_index``
+            the op index after the merge at which the sequential
+            protocol applies it everywhere — the first merge whose pass
+            finds it Δ-overdue or its dependencies at or below every
+            replica clock, as the protocol's own clocks stand then (the
+            batch's slice of :meth:`schedule_stream`, ``NEVER`` where no
+            merge of the stream applies it) — and becomes visible at
+            remote replicas from that op index on, both for writes
+            inside the batch and for writes still pending from earlier
+            batches.  Without ``apply_index`` the batch is scheduled as
+            a stream of its own.
 
         Without ``op_step0`` the batch has plain scalar-loop semantics
         (writes visible at their coordinator only) — the bit-exact mode

@@ -685,6 +685,7 @@ class EpochEngine:
                 )
 
         streams, batched_shards, tail_shards = [], [], []
+        schedule_counts = []
         for s in range(c.n_shards):
             with trace_lib.span(
                 "engine.prepare.stream", shard=s, ops=c.shard_ops
@@ -712,11 +713,15 @@ class EpochEngine:
                         k: stream[k][-max(rem, 1):]
                         for k in stream_lib.OP_COLS
                     }
+                counts = None
                 with trace_lib.span("engine.prepare.schedule", shard=s):
                     if store.sync_every > 1:
-                        apply_idx = np.asarray(store.schedule_stream(
-                            stream["client"], stream["home"], stream["kind"]
-                        ))
+                        sched = store.schedule_stream(
+                            stream["client"], stream["home"], stream["kind"],
+                            counters=True,
+                        )
+                        apply_idx = np.asarray(sched.apply)
+                        counts = sched.counts()
                     else:
                         apply_idx = np.zeros(c.shard_ops, np.int32)
                     apply_idx = stream_lib.clamp_apply_idx(
@@ -727,9 +732,10 @@ class EpochEngine:
                 ].reshape(n_rounds, sub)
                 tail["apply_idx"] = apply_idx[-max(rem, 1):]
             else:
-                batched, tail = stream_lib.batch_inputs(
+                batched, tail, counts = stream_lib.batch_inputs(
                     stream, store, sub, n_rounds, rem, emulate, shard=s
                 )
+            schedule_counts.append(counts)
             if masks is not None:
                 batched = {**batched, **masks}
                 tail = {**tail, **tail_masks}
@@ -740,7 +746,8 @@ class EpochEngine:
             "store": store, "run": run, "schedule": schedule,
             "masks": masks, "tail_masks": tail_masks,
             "streams": streams, "batched": batched_shards,
-            "tails": tail_shards, "sub": sub, "rem": rem,
+            "tails": tail_shards, "schedule_counts": schedule_counts,
+            "sub": sub, "rem": rem,
             "n_rounds": n_rounds, "emulate": emulate,
         }
 

@@ -581,6 +581,24 @@ def _cost_attribution(result: dict[str, Any]) -> dict[str, float]:
     }
 
 
+def _schedule_counts(prep: dict) -> None:
+    """Fetch each tenant's scheduler counters, attach them to a span,
+    and refuse a schedule whose pending window overflowed: its apply
+    points are not the sequential protocol's."""
+    for shard, counts in enumerate(prep.get("schedule_counts") or ()):
+        if counts is None:
+            continue
+        counts = {k: int(v) for k, v in jax.device_get(counts).items()}
+        with trace_lib.span("engine.assemble.schedule", shard=shard,
+                            **counts):
+            pass
+        if counts["overflow"]:
+            raise RuntimeError(
+                f"the cadence scheduler's pending window overflowed "
+                f"(tenant {shard}: {counts})"
+            )
+
+
 def assemble(
     engine,
     prep: dict,
@@ -594,6 +612,7 @@ def assemble(
     with trace_lib.span(
         "engine.assemble", replay=prep.get("replay_id"), count_compiles=True,
     ):
+        _schedule_counts(prep)
         if config.faults is not None:
             result = assemble_faulty(
                 config, prep, w, cfg, pricing, _return_state

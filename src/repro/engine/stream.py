@@ -105,14 +105,17 @@ def batch_inputs(
     stream: dict[str, np.ndarray], store: ReplicatedStore,
     sub: int, n_rounds: int, rem: int, emulate: bool,
     shard: int = 0,
-) -> tuple[dict[str, Any], dict[str, Any]]:
-    """(batched, tail) scan inputs for one stream under one plan.
+) -> tuple[dict[str, Any], dict[str, Any], dict[str, Any] | None]:
+    """(batched, tail, schedule counters) scan inputs for one stream
+    under one plan.
 
     Rounds carry their first op's global index (``step0``); the
     emulated-cadence levels also carry the precomputed apply-point
-    schedule, sliced per round.  ``rem == 0`` still builds a one-op
-    dummy tail (the jitted runner ignores it).  ``shard`` names the
-    tenant in the spans.
+    schedule, sliced per round, and return the scheduler's counters as
+    device scalars (``None`` where no schedule is built), for result
+    assembly to read.  ``rem == 0`` still builds a one-op dummy tail
+    (the jitted runner ignores it).  ``shard`` names the tenant in the
+    spans.
     """
     with trace_lib.span(
         "engine.prepare.batch", shard=shard, ops=n_rounds * sub + rem
@@ -123,16 +126,20 @@ def batch_inputs(
         }
         batched["step0"] = jnp.arange(n_rounds, dtype=jnp.int32) * sub
         tail = {k: jnp.asarray(stream[k][-max(rem, 1):]) for k in OP_COLS}
+    counts = None
     if emulate and store.sync_every > 1:
         with trace_lib.span("engine.prepare.schedule", shard=shard):
-            apply_idx = store.schedule_stream(
-                stream["client"], stream["home"], stream["kind"]
+            sched = store.schedule_stream(
+                stream["client"], stream["home"], stream["kind"],
+                counters=True,
             )
+        apply_idx = sched.apply
+        counts = sched.counts()
         batched["apply_idx"] = apply_idx[: n_rounds * sub].reshape(
             n_rounds, sub
         )
         tail["apply_idx"] = apply_idx[-max(rem, 1):]
-    return batched, tail
+    return batched, tail, counts
 
 
 def fault_epoch_inputs(

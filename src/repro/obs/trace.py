@@ -25,6 +25,9 @@ The spans of a replay (``engine/replay.py``, ``engine/stream.py``,
     engine.assemble          compiles, cache_loads, replay
       engine.assemble.fetch  the first host read of the carry, where a
                              caller that has not blocked waits for the scan
+      engine.assemble.schedule  shard, dep_applied, delta_applied,
+                             pending_peak, overflow (the cadence
+                             scheduler's counters, emulated cadences)
       engine.assemble.audit  shard, compiles, cache_loads
       engine.assemble.obs
 

@@ -144,3 +144,20 @@ def test_audit_no_false_positives_on_serial_history(seed):
                         version=version[r], replica=0, vc=vc)
     res = audit.audit(t, delta=4)
     assert int(res.n_violations) == 0
+
+
+def test_severity_ratio_is_the_ieee_quotient():
+    """The audit's severity divides on the device by an int32 long
+    division: the float32 quotient numpy's IEEE division gives, on any
+    backend (a TPU's float32 division can be one unit off)."""
+    import jax
+
+    rng = np.random.default_rng(15)
+    num = np.concatenate([[0, 1, 7, 5, 3, 2 ** 24 - 1, 6],
+                          rng.integers(0, 2 ** 24, 4000)])
+    den = np.concatenate([[1, 1, 7, 3, 1, 2 ** 24 - 1, 12_582_912],
+                          rng.integers(1, 2 ** 24, 4000)])
+    got = np.asarray(jax.vmap(audit.ratio_f32)(jnp.asarray(num, jnp.int32),
+                                         jnp.asarray(den, jnp.int32)))
+    want = num.astype(np.float32) / den.astype(np.float32)
+    np.testing.assert_array_equal(got, want)

@@ -172,3 +172,19 @@ def test_the_tracer_clock_is_the_profilers(tmp_path):
              for e in line.events if e.name == "engine.clock"]
     (span,) = tracer.spans("engine.clock")
     assert abs((start + ev) / 1e3 - span["ts"]) < 5e3     # within 5 ms
+
+
+def test_assembly_carries_the_schedulers_counters():
+    """An X-STCC replay schedules its stream in prepare; assembly fetches
+    the scheduler's counters with the rest and sets them on a span."""
+    config = EngineConfig(ConsistencyLevel.X_STCC, n_ops=512, batch_size=128,
+                          n_resources=40)
+    _, tracer = trace_lib.traced_run(config, WORKLOAD_A)
+    (sched,) = tracer.spans("engine.assemble.schedule")
+    args = sched["args"]
+    assert args["shard"] == 0 and args["overflow"] == 0
+    assert args["dep_applied"] + args["delta_applied"] > 0
+    assert 0 < args["pending_peak"] <= 16
+    (prep,) = tracer.spans("engine.prepare.schedule")
+    (assemble,) = tracer.spans("engine.assemble")
+    assert prep["ts"] < assemble["ts"] <= sched["ts"]

@@ -148,6 +148,24 @@ def test_floor_scatters_need_no_relayout(one_chip, on_tpu):
     assert relayouts == []
 
 
+def test_cadence_scheduler_is_one_kernel(one_chip, on_tpu):
+    """The X-STCC cadence (merge every 8, Δ 8) over a 1,048,576-op
+    stream: the whole schedule is the Pallas kernel, with no XLA loop
+    around it, and the store picks it on a TPU."""
+    from repro.core import replicated_store as rs
+    from repro.kernels import cadence_schedule as cs
+
+    assert cs.resolve_impl(8, 16, 3, rs.pending_periods(8, 8)) == "pallas"
+    assert cs.resolve_impl(8, 16, 3, 17) == "scan"
+    n = 1 << 20
+    sched = rs._stream_scheduler(8, 8, 16, 3, impl="pallas")
+    text = sched.lower(*[
+        jax.ShapeDtypeStruct((n,), I32, sharding=one_chip)] * 3
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"\bwhile\(", text)
+
+
 def test_kernels_never_interpreted_on_tpu(on_tpu):
     assert ops._interpret(None) is False
     assert ops._interpret(False) is False
