@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from repro.core import vector_clock as vclock
 from repro.core.consistency import ConsistencyLevel
+from repro.kernels import vclock_chain
 
 Array = jax.Array
 
@@ -247,6 +248,32 @@ class BatchResult(NamedTuple):
     slot: Array         # (B,) int32
 
 
+def clock_chain(
+    session_vc: Array, replica_vc: Array, client: Array, replica: Array,
+    is_write: Array,
+) -> tuple[Array, Array, Array]:
+    """The receive rule over a batch, in op order, as a ``lax.scan``:
+    ``(session_vc, replica_vc, vcs)`` after the ops, ``vcs`` each op's
+    clock.  The CPU path of :func:`apply_op_batch` and the oracle of the
+    TPU kernel (``repro.kernels.vclock_chain``)."""
+    def clock_step(carry, op):
+        svcs, rvcs = carry
+        ci, pi, wi = op
+        svc = jnp.maximum(svcs[ci], rvcs[pi]).at[ci].add(1)
+        svcs = svcs.at[ci].set(svc)
+        rvcs = jnp.where(wi, rvcs.at[pi].max(svc), rvcs)
+        return (svcs, rvcs), svc
+
+    # Unrolling amortizes the scan's per-step loop overhead — the body
+    # is ~tens of scalar ops on two small rows, far below the iteration
+    # cost of an un-unrolled lax.scan on CPU.
+    (session_vc, replica_vc), vcs = jax.lax.scan(
+        clock_step, (session_vc, replica_vc), (client, replica, is_write),
+        unroll=8,
+    )
+    return session_vc, replica_vc, vcs
+
+
 def apply_op_batch(
     state: ClusterState,
     *,
@@ -277,8 +304,9 @@ def apply_op_batch(
         in the sequential loop between merges;
       * vector clocks: the session/replica clock chaining is inherently
         sequential (each op's clock merges state its predecessors wrote),
-        so it runs as a length-B scan over two small rows — every other
-        state component is a closed-form segment/scatter op.
+        so it runs as a length-B chain over two small rows
+        (:func:`clock_chain`; on a TPU the ``vclock_chain`` kernel) —
+        every other state component is a closed-form segment/scatter op.
 
     Merge cadences finer than the batch are injected through the
     closed-form visibility predicate ``op_index(i) >= apply_index(j)``:
@@ -360,23 +388,14 @@ def apply_op_batch(
     version_out = jnp.where(is_w, ver_w, served)
     admissible = jnp.where(is_w, True, adm)
 
-    # -- vector clocks (exact sequential chaining, small scan) ---------------
+    # -- vector clocks (exact sequential chaining) ---------------------------
     if with_clocks:
-        def clock_step(carry, op):
-            svcs, rvcs = carry
-            ci, pi, wi = op
-            svc = jnp.maximum(svcs[ci], rvcs[pi]).at[ci].add(1)
-            svcs = svcs.at[ci].set(svc)
-            rvcs = jnp.where(wi, rvcs.at[pi].max(svc), rvcs)
-            return (svcs, rvcs), svc
-
-        # Unrolling amortizes the scan's per-step loop overhead — the
-        # body is ~tens of scalar ops on two small rows, far below the
-        # iteration cost of an un-unrolled lax.scan on CPU.
+        impl = vclock_chain.resolve_impl(state.session_vc.shape[0], P)
         with jax.named_scope("vclock_scan"):
-            (session_vc, replica_vc), vcs = jax.lax.scan(
-                clock_step, (state.session_vc, state.replica_vc),
-                (c, p, is_w), unroll=8,
+            chain = (vclock_chain.vclock_chain if impl == "pallas"
+                     else clock_chain)
+            session_vc, replica_vc, vcs = chain(
+                state.session_vc, state.replica_vc, c, p, is_w
             )
     else:
         session_vc = state.session_vc

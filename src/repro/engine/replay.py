@@ -34,6 +34,7 @@ from repro.engine.config import EngineConfig
 from repro.engine import stream as stream_lib
 from repro.gossip.scheduler import GossipConfig, gossip_pairs
 from repro.kernels import ops as kernel_ops
+from repro.kernels import vclock_chain
 from repro.obs import metrics as obs_lib
 from repro.obs import trace as trace_lib
 
@@ -753,6 +754,16 @@ class EpochEngine:
 
     # -- replay -----------------------------------------------------------
 
+    def _clock_impl(self, prep) -> str:
+        """The clock chain the round step runs: the ``vclock_chain``
+        kernel (``"pallas"``), the ``lax.scan`` (``"scan"``), or none
+        (``"off"``: the lean cadence path skips it)."""
+        c = self.config
+        if c.lean and prep["emulate"]:
+            return "off"
+        return vclock_chain.resolve_impl(c.shard_clients,
+                                         prep["store"].n_replicas)
+
     def replay(self, w) -> dict[str, Any]:
         """Run the whole workload through the device-resident scan.
 
@@ -813,6 +824,8 @@ class EpochEngine:
                         out = jax.vmap(run.jitted)(batched, tail)
                         layout = {"mode": "vmap", "devices": 1}
                     launch.set(jit_entries=jit_entries() - j0)
+                    if launch:
+                        launch.set(clock_impl=self._clock_impl(prep))
             else:
                 with trace_lib.span("engine.place") as place:
                     b = {k: jnp.asarray(v)
@@ -827,6 +840,8 @@ class EpochEngine:
                 ) as launch:
                     out = run(b, t)
                     launch.set(jit_entries=jit_entries() - j0)
+                    if launch:
+                        launch.set(clock_impl=self._clock_impl(prep))
                 layout = {"mode": "single", "devices": 1}
         if isinstance(out, tuple):
             out, per_round = out

@@ -11,6 +11,7 @@ changes no result; with nothing recording a span does nothing.
 import contextlib
 
 import jax
+import pytest
 
 from repro.core.consistency import ConsistencyLevel
 from repro.engine import EngineConfig, EpochEngine
@@ -129,6 +130,21 @@ def test_the_launch_span_counts_the_jit_entries_it_saw(monkeypatch):
         EpochEngine(CONFIG).replay(WORKLOAD_A)
     (launch,) = tracer.spans("engine.launch")
     assert launch["args"]["jit_entries"] == 2
+
+
+@pytest.mark.parametrize("config,impl", [
+    (CONFIG, "scan"),
+    (EngineConfig(ConsistencyLevel.X_STCC, n_ops=336, batch_size=112,
+                  n_resources=40, audit=False, lean=True), "off"),
+], ids=["clocks", "lean"])
+def test_the_launch_span_names_the_clock_chain(config, impl):
+    """``clock_impl`` says which clock chain the round step ran: the
+    scan on a CPU, none on the lean cadence path."""
+    tracer = trace_lib.Tracer()
+    with tracer.recording():
+        EpochEngine(config).replay(WORKLOAD_A)
+    (launch,) = tracer.spans("engine.launch")
+    assert launch["args"]["clock_impl"] == impl
 
 
 def test_spans_are_inert_when_nothing_records():

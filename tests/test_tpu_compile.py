@@ -166,6 +166,49 @@ def test_cadence_scheduler_is_one_kernel(one_chip, on_tpu):
     assert not re.search(r"\bwhile\(", text)
 
 
+def test_clock_chain_is_one_kernel(one_chip, on_tpu):
+    """Two rounds of ``apply_op_batch`` at the engine's shapes (16
+    sessions, 3 replicas, 5M rows, B=4096): the clock chain is the
+    ``vclock_scan`` kernel, and no loop over the (16, 16) session or
+    (3, 16) replica clock rows is left in the round body; an oversize
+    shape keeps the scan."""
+    from repro.core import xstcc
+    from repro.kernels import vclock_chain
+
+    assert vclock_chain.resolve_impl(16, 3) == "pallas"
+    assert vclock_chain.resolve_impl(256, 3) == "scan"
+    assert vclock_chain.resolve_impl(16, 24) == "scan"
+    c, r, b = 16, 5_000_000, 4096
+    state = jax.eval_shape(lambda: xstcc.make_cluster(3, c, r, 2 * b))
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        state,
+    )
+
+    def rounds(state, ops_):
+        def step(st, op):
+            res = xstcc.apply_op_batch(
+                st, client=op[0], replica=op[1], resource=op[2],
+                kind=op[3], ingest="pallas",
+            )
+            return res.state, res.vc
+        return jax.lax.scan(step, state, ops_)
+
+    ops_ = jax.ShapeDtypeStruct((2, 4, b), I32, sharding=one_chip)
+    text = jax.jit(rounds).lower(state, ops_).compile().as_text()
+    assert any(line.lstrip().startswith("%vclock_scan")
+               and "tpu_custom_call" in line for line in text.splitlines())
+    # The round loop carries the flat floors; any other loop carrying a
+    # clock row is a clock chain left in XLA.
+    clock_loops = [
+        line for line in text.splitlines()
+        if re.search(r"\bwhile\(", line)
+        and re.search(r"s32\[(16|3),16\]", line)
+        and f"s32[{c * r}]" not in line
+    ]
+    assert clock_loops == []
+
+
 def test_kernels_never_interpreted_on_tpu(on_tpu):
     assert ops._interpret(None) is False
     assert ops._interpret(False) is False
